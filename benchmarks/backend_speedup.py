@@ -7,11 +7,9 @@ cycle loop ("RTL simulator"), (b) the compiled single-netlist engine,
 latency-insensitive semantics, bit-identical results, only the backend
 changes.
 
-The compiled backend is ASSERTED to beat the interpreted one (PR 2's
-BENCH_PR2.json recorded it at 0x — root cause: the XLA:CPU thunk runtime's
-per-op dispatch overhead inside compiled loops, now disabled at
-``repro.core`` import by ``compat.tune_cpu_runtime``).  Wall times are
-min-of-N to shed scheduler noise.
+The compiled backend is ASSERTED to beat the interpreted one.  Wall
+times are min-of-N to shed scheduler noise; they are host timings of
+whatever backend JAX runs on, not device measurements.
 """
 import time
 

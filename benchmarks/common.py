@@ -38,7 +38,11 @@ def timeit(fn, *args, n: int = 5, warmup: int = 2):
 
 
 def run_subprocess(code: str, devices: int = 4, timeout: int = 600) -> str:
+    """Run ``code`` in a child on ``devices`` simulated CPU devices.  The
+    child is pinned to the CPU: the parent has imported JAX and, on a TPU
+    host, holds the chip, which a second process cannot share."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(

@@ -18,8 +18,11 @@ This example is that scenario on the tiered GraphEngine:
     the global sum** — one equality that witnesses every packet crossing
     every tier.
 
-Run (8 simulated devices are forced automatically when only one real
-device is visible):
+The 2 x (2x2) granule layout maps onto the devices that exist
+(``fold_mesh``): as many leading granule axes as fit become mesh axes and
+the rest fold onto each device as batch axes — one TPU chip runs all 8
+granules, 8 devices run one each.  On the CPU the script re-executes
+itself with 8 simulated devices first.
 
     PYTHONPATH=src python examples/wafer_scale.py               # 256x256
     PYTHONPATH=src python examples/wafer_scale.py --rows 64 --cols 64
@@ -33,23 +36,25 @@ import time
 
 N_DEVICES = 8
 
-# Re-exec with fake devices ONLY as the real main module: the procs
-# engine's spawned workers re-import this file as __mp_main__ (with the
-# device flag deliberately stripped), and re-execing there would fork-bomb.
+import jax  # noqa: E402
+
+# Re-exec with fake devices only on the CPU, and ONLY as the real main
+# module: the procs engine's spawned workers re-import this file as
+# __mp_main__ (with the device flag deliberately stripped), and re-execing
+# there would fork-bomb.
 if __name__ == "__main__" and "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
+        os.environ.get("XLA_FLAGS", "") and jax.default_backend() == "cpu":
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={N_DEVICES} "
         + os.environ.get("XLA_FLAGS", "")
     ).strip()
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
-import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs.manycore import WAFER  # noqa: E402
-from repro.core import Simulation, tiered_grid_partition  # noqa: E402
-from repro.core.compat import make_mesh  # noqa: E402
+from repro.core import Simulation, fold_mesh, tiered_grid_partition  # noqa: E402
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.distributed import GraphEngine  # noqa: E402
 from repro.core.graph import ChannelGraph  # noqa: E402
 from repro.hw.manycore import (  # noqa: E402
@@ -61,7 +66,8 @@ def build_engine(R: int, C: int, k_inner: int, k_outer: int,
                  capacity: int = WAFER.queue_capacity,
                  engine: str = "graph", batch_signatures: bool = False,
                  overlap="auto", hosts=None) -> tuple[GraphEngine, np.ndarray]:
-    """Torus fabric on a (2 pods) x (2x2 granules/pod) tiered mesh — or,
+    """Torus fabric on a (2 pods) x (2x2 granules/pod) tiered layout,
+    folded onto the devices that exist (``fold_mesh``) — or,
     with ``engine="procs"``, on a (2 pods) x (2 workers/pod) fleet of
     free-running OS processes over shared-memory queues (no mesh at all:
     the paper's actual deployment model, DESIGN.md §Runtime).
@@ -91,7 +97,7 @@ def build_engine(R: int, C: int, k_inner: int, k_outer: int,
         return ProcsEngine(graph, ptree, timeout=120.0,
                            batch_signatures=batch_signatures,
                            overlap=overlap, hosts=hosts), values
-    mesh = make_mesh((2, 2, 2), ("pod", "gr", "gc"))
+    mesh, batch_axes = fold_mesh({"pod": 2, "gr": 2, "gc": 2})
     part = tiered_grid_partition(R, C, [(2, 1), (2, 2)])
     if engine == "fused":
         from repro.core.fused import FusedEngine as Engine
@@ -100,7 +106,7 @@ def build_engine(R: int, C: int, k_inner: int, k_outer: int,
     eng = Engine(
         graph, part, mesh,
         tiers=[(("pod",), k_outer), ((("gr", "gc")), k_inner)],
-        overlap=overlap,
+        batch_axes=batch_axes, overlap=overlap,
     )
     return eng, values
 
@@ -131,6 +137,7 @@ def main() -> None:
     if args.hosts and args.engine != "procs":
         ap.error("--hosts requires --engine procs")
     R, C = args.rows, args.cols
+    enable_compile_cache()
 
     print(f"wafer-scale fabric: {R}x{C} torus = {R * C} cores, "
           f"{len(jax.devices())} devices, engine={args.engine}")

@@ -23,12 +23,8 @@ engine backend.
   perfmodel   rate control + N_meas error model (§II-C)
   fastgrid    hand-specialized systolic Pallas preset of the fused family
   pipeline    LM pipeline parallelism on the same channel semantics
-  compat      version-tolerant jax.make_mesh / jax.shard_map wrappers
+  compat      jax.make_mesh (Auto axes) / jax.shard_map wrappers
 """
-from .compat import tune_cpu_runtime as _tune_cpu_runtime
-
-_tune_cpu_runtime()  # before any backend init — see compat.tune_cpu_runtime
-
 from .block import Block
 from .network import Network, NetworkSim, NetworkState
 from .graph import (
@@ -38,7 +34,7 @@ from .graph import (
 )
 from .queue import QueueArray, make_queues, DEFAULT_CAPACITY
 from .distributed import (
-    GraphEngine, GraphState, GridEngine, edge_color_routes,
+    GraphEngine, GraphState, GridEngine, edge_color_routes, fold_mesh,
     merge_compatible_classes, route_shift_groups,
 )
 from .fastgrid import RegisterGridEngine

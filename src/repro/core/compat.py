@@ -1,57 +1,21 @@
-"""Version-tolerant wrappers over fast-moving JAX APIs.
-
-The repo targets the JAX the container ships; newer call signatures
-(``jax.make_mesh(axis_types=...)``, ``jax.shard_map(check_vma=...)``) are
-accepted here and degraded gracefully so engines, tests, and benchmarks
-share one spelling:
+"""One spelling of the mesh and ``shard_map`` calls for the installed JAX.
 
     from repro.core.compat import make_mesh, shard_map
 
-Both helpers are pure call-forwarders — no behavioural shimming beyond
-dropping/renaming keywords the installed JAX does not know about.
+``jax.make_mesh`` builds *Explicit* axes by default; the engines index and
+push host packets into mesh-sharded state with plain array ops, which only
+Auto axes allow, so every mesh here is built with Auto axes.  The engines'
+``shard_map`` bodies mix per-granule state with collectives in ways the
+varying-manual-axes checker rejects, so the check is off.
 """
 from __future__ import annotations
 
-import inspect
-import os
 from typing import Any, Callable, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_mesh", "shard_map", "tune_cpu_runtime"]
-
-
-def tune_cpu_runtime() -> None:
-    """Disable the XLA:CPU *thunk* runtime for this process (perf, §Perf).
-
-    The thunk runtime this jaxlib ships pays a per-op dispatch cost inside
-    compiled while-loops that dwarfs the actual work of cycle-stepped
-    simulation (tiny tensors, many ops per cycle): the single-netlist
-    engine ran ~4x slower than with the legacy emitter — the
-    "compiled backend at 0x speedup" regression in BENCH_PR2.json.
-    Measured on ``benchmarks.backend_speedup``: 30.2 -> 7.5 us/cycle.
-
-    Must run before the CPU backend initializes — XLA reads the flags at
-    client creation, so if user code ran a jax computation before
-    importing ``repro.core`` the mutation is set but has NO effect for
-    that process (import ``repro.core`` first, or export the flag in the
-    environment).  Called at ``repro.core`` import; a no-op if the user
-    already pinned the flag in ``XLA_FLAGS`` (either value).  TPU/GPU
-    lowering ignores the flag entirely.
-    """
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" in flags:
-        return
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_cpu_use_thunk_runtime=false"
-    ).strip()
-
-
-def _supports_kwarg(fn: Callable, name: str) -> bool:
-    try:
-        return name in inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # builtins / C callables
-        return False
+__all__ = ["make_mesh", "shard_map"]
 
 
 def make_mesh(
@@ -59,36 +23,16 @@ def make_mesh(
     axis_names: Sequence[str],
     *,
     devices: Any = None,
-    axis_types: Any = None,
 ):
-    """``jax.make_mesh`` that tolerates JAX versions without ``axis_types``.
-
-    ``axis_types`` (an explicit Auto/Manual marker in newer JAX) is dropped
-    when unsupported — older versions treat every axis as Auto, which is the
-    only mode this repo uses.
-    """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _supports_kwarg(jax.make_mesh, "axis_types"):
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
+    )
 
 
-def shard_map(f: Callable, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; older versions only
-    have ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  The
-    engines always disable the replication/VMA check: their bodies mix
-    per-granule state with collectives in ways the checker rejects.
-    """
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        kwargs: dict[str, Any] = {}
-        if _supports_kwarg(impl, "check_vma"):
-            kwargs["check_vma"] = False if check_vma is None else check_vma
-        return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+def shard_map(f: Callable, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )

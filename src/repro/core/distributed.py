@@ -87,7 +87,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import queue as qmod
 from ..obs.registry import REGISTRY
 from .block import Block
-from .compat import shard_map
+from .compat import make_mesh, shard_map
 from .graph import (
     ChannelGraph, PartitionTree, Tier, _rank_within, grid_partition,
     lower_partition, normalize_partition, normalize_tiers,
@@ -153,6 +153,32 @@ class _ExchangeClass:
     # between batch rows of one device (no collective at all).  None on
     # unbatched engines (where ``perm`` itself is the ppermute).
     real_perm: tuple | None = static_field(default=None)
+
+
+def fold_mesh(axis_sizes: dict[str, int], devices=None):
+    """``(mesh, batch_axes)`` for a granule layout on the devices that exist.
+
+    ``axis_sizes`` names the partition's granule axes, outermost first.
+    The longest leading run of them whose sizes multiply to at most the
+    device count becomes the mesh (over the first that-many devices); the
+    remaining innermost axes fold onto each device as ``batch_axes`` — the
+    suffix form ``GraphEngine`` requires.  With one device every axis is
+    folded; ``batch_axes`` is None when none is."""
+    devs = list(jax.devices() if devices is None else devices)
+    names = list(axis_sizes)
+    n_real, n_dev = 0, 1
+    while (n_real < len(names)
+           and n_dev * axis_sizes[names[n_real]] <= len(devs)):
+        n_dev *= axis_sizes[names[n_real]]
+        n_real += 1
+    real = names[:n_real]
+    if real:
+        mesh = make_mesh(tuple(axis_sizes[a] for a in real), tuple(real),
+                         devices=devs[:n_dev])
+    else:
+        mesh = make_mesh((1,), ("device",), devices=devs[:1])
+    batch = {a: int(axis_sizes[a]) for a in names[n_real:]}
+    return mesh, (batch or None)
 
 
 def _dealias_for_donation(tree: PyTree) -> PyTree:

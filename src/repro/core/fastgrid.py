@@ -291,7 +291,7 @@ class RegisterGridEngine:
             return _unsq(self._epoch(_sq(state)))
 
         return shard_map(run, mesh=self.mesh, in_specs=self._spec,
-                         out_specs=self._spec, check_vma=False)
+                         out_specs=self._spec)
 
     def run_epochs(
         self, state: RegGridState, n_epochs: int, *, donate: bool = True
@@ -316,7 +316,7 @@ class RegisterGridEngine:
 
             self._cache[key] = jax.jit(
                 shard_map(run, mesh=self.mesh, in_specs=self._spec,
-                          out_specs=self._spec, check_vma=False),
+                          out_specs=self._spec),
                 donate_argnums=(0,) if donate else (),
             )
         if donate:
@@ -372,7 +372,7 @@ class RegisterGridEngine:
                 anchor,  # strong ref: keeps the keyed id alive
                 jax.jit(
                     shard_map(run, mesh=self.mesh, in_specs=self._spec,
-                              out_specs=self._spec, check_vma=False),
+                              out_specs=self._spec),
                     donate_argnums=(0,) if donate else (),
                 ),
             )

@@ -112,14 +112,15 @@ class FusedEngine(GraphEngine):
 
     Accepts everything ``GraphEngine`` accepts, plus:
 
-    fuse:    epoch-body strategy — "auto" (one Pallas kernel on TPU, one
-             ``fori_loop`` body elsewhere; overridable via the
-             ``REPRO_EPOCH_MODE`` env var), or explicitly "xla" |
-             "unroll" | "pallas" (see ``kernels.granule_step``).
+    fuse:    epoch-body strategy — "auto" (the ``fori_loop`` body on every
+             backend; overridable via the ``REPRO_EPOCH_MODE`` env var),
+             or explicitly "xla" | "unroll" | "pallas" (see
+             ``kernels.granule_step``; "pallas" does not compile for a
+             TPU and fails there at compile time).
     pallas_interpret: run the Pallas path in interpret mode.  "auto"
-             (default) interprets everywhere but TPU, so ``fuse="pallas"``
-             is live on CPU CI; booleans force either way
-             (``REPRO_PALLAS_INTERPRET`` overrides both).
+             (default) interprets off-TPU, so ``fuse="pallas"`` is live
+             on CPU CI; on a TPU only an explicit ``True`` interprets
+             (off-TPU ``REPRO_PALLAS_INTERPRET`` overrides the argument).
     batch_axes: signature batching — see ``GraphEngine``.  On the fused
              engine a batched granule axis additionally unlocks the
              *resident multi-epoch kernel*: every tier whose exchanges

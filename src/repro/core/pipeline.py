@@ -91,7 +91,6 @@ class Pipeline:
             mesh=self.mesh,
             in_specs=(P(self.axis), P()),
             out_specs=P(),
-            check_vma=False,
         )(stage_params, x)
 
 

@@ -11,14 +11,20 @@ computation instead of ~10 interpreted queue ops per cycle:
   * ``mode="xla"`` — one ``fori_loop`` whose carry is the compact
     register-file state (the deep queue buffers and lookup tables stay
     out of the carry).  One jitted XLA computation per epoch; the default
-    off-TPU.
+    on every backend.
   * ``mode="unroll"`` — the cycle body is Python-unrolled into a single
     straight-line computation.  Opt-in: on XLA:CPU the loop form measures
     ~3x faster, but the unrolled form can win where cross-cycle fusion
     pays (small K, wide granules).
   * ``mode="pallas"`` — the same body wrapped in ONE ``pallas_call`` so
-    the epoch executes with the granule state resident in VMEM (TPU).
-    ``interpret=True`` runs the kernel path on CPU for CI.
+    the epoch executes with the granule state resident in kernel memory.
+    Opt-in, and CPU-only for now: ``interpret=True`` runs it under the
+    Pallas interpreter.  The TPU compiler refuses it — the cycle body's
+    row gathers (``fronts[rxm]``) lower to N-D gathers that Mosaic does
+    not support ("Only 2D gather is supported"), and the rank-0 cycle
+    counter is not a legal block — so ``fuse="pallas"`` on a TPU fails
+    at compile time and never falls back (``tests/test_tpu_compile.py``
+    rehearses that refusal for a v5e).
 
 Contract for ``cycle_fn``: pytree -> pytree with identical treedef,
 shapes, and dtypes (the fused engine's local cycle satisfies it; the
@@ -56,10 +62,10 @@ def resolve_mode(mode: str = "auto") -> str:
     :func:`resolve_interpret`) without threading a flag through every
     engine.  An explicit non-"auto" argument always wins over the env.
 
-    "auto" resolves to the Pallas kernel on TPU and the ``fori_loop`` body
-    elsewhere — measured on XLA:CPU the loop beats full unrolling ~3x (the
-    straight-line body defeats the emitter's locality), so "unroll" is
-    opt-in only.
+    "auto" resolves to the ``fori_loop`` body on every backend: the
+    Pallas body does not compile for a TPU (see the module docstring), and
+    on XLA:CPU the loop beats full unrolling ~3x (the straight-line body
+    defeats the emitter's locality), so "unroll" is opt-in only.
     """
     if mode == "auto":
         env = os.environ.get("REPRO_EPOCH_MODE", "auto").strip().lower()
@@ -68,25 +74,25 @@ def resolve_mode(mode: str = "auto") -> str:
                 raise ValueError(
                     f"REPRO_EPOCH_MODE={env!r} not in {_MODES}")
             return env
-    if mode != "auto":
-        return mode
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "xla" if mode == "auto" else mode
 
 
 def resolve_interpret(interpret: Any = "auto") -> bool:
     """Resolve the pallas ``interpret`` knob.
 
-    ``"auto"`` means: run the kernel natively on TPU, fall back to the
-    Pallas interpreter everywhere else — so ``mode="pallas"`` is never
-    dead code off-TPU (the ISSUE 6 CI requirement).  The env override
-    ``REPRO_PALLAS_INTERPRET=0|1`` forces either way (e.g. to exercise the
-    interpreter on TPU hosts).  Booleans pass through unchanged.
+    On a TPU the kernel runs natively unless the caller passes ``True``
+    itself: neither ``"auto"`` nor the environment can put the chip into
+    the interpreter.  Elsewhere ``"auto"`` means the Pallas interpreter
+    (so ``mode="pallas"`` is never dead code on CPU CI), and the env
+    override ``REPRO_PALLAS_INTERPRET=0|1`` beats the argument.
     """
+    if jax.default_backend() == "tpu":
+        return interpret is True
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
     if env:
         return env not in ("0", "false", "False")
     if interpret == "auto":
-        return jax.default_backend() != "tpu"
+        return True
     return bool(interpret)
 
 

@@ -257,10 +257,22 @@ def rglru(x, a, h0=None, *, block_t=256, block_d=256, use_kernel=True, backend=N
 
 
 # ===================================================== systolic
-@functools.partial(jax.jit, static_argnames=("k_cycles",))
 def systolic_step(state: dict, k_cycles: int) -> dict:
-    """K fused cycles of a systolic tile (see kernels/systolic_step.py)."""
-    return _sy.systolic_step(state, k_cycles, interpret=not _on_tpu())
+    """K fused cycles of a systolic tile (see kernels/systolic_step.py).
+
+    Refused on a TPU: the kernel compiles for a v5e, but its results there
+    have never been checked against ``engine="single"`` on a chip, so a
+    wrong answer would go unnoticed.  Elsewhere it runs interpreted."""
+    if _on_tpu():
+        raise NotImplementedError(
+            "engine='register' has not been checked on a TPU; use "
+            "engine='fused', which runs any systolic grid bit-identically")
+    return _systolic_step(state, k_cycles)
+
+
+@functools.partial(jax.jit, static_argnames=("k_cycles",))
+def _systolic_step(state: dict, k_cycles: int) -> dict:
+    return _sy.systolic_step(state, k_cycles, interpret=True)
 
 
 # ===================================================== slstm

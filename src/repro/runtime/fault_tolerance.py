@@ -192,17 +192,29 @@ def find_stall_cycle(edges: dict[int, int]) -> list[int] | None:
 
 
 def read_log_tail(path: str | None, max_bytes: int = 2048) -> str:
-    """Last ``max_bytes`` of a worker's captured log ('' when absent)."""
+    """Last ``max_bytes`` of a worker's captured log ('' when absent).
+
+    A worker's start banner (its first ``[worker ...]`` line) names the
+    granule(s) it simulates; when library warnings have pushed it out of
+    the tail, it is kept ahead of the tail, so every diagnosis still says
+    which granule died."""
     if not path or not os.path.exists(path):
         return ""
     try:
         with open(path, "rb") as f:
+            head = f.read(max_bytes)
             f.seek(0, os.SEEK_END)
-            size = f.tell()
-            f.seek(max(0, size - max_bytes))
-            return f.read().decode(errors="replace").strip()
+            start = max(0, f.tell() - max_bytes)
+            f.seek(start)
+            tail = f.read().decode(errors="replace").strip()
     except OSError:
         return ""
+    banner = next(
+        (ln for ln in head.decode(errors="replace").splitlines()
+         if ln.startswith("[worker ")), None)
+    if banner is not None and banner not in tail:
+        return f"{banner}\n...\n{tail}"
+    return tail
 
 
 class ProcessMonitor:

@@ -65,6 +65,7 @@ from typing import Any, Callable, Sequence
 import numpy as np
 
 from ..core import queue as qmod
+from ..core.compile_cache import enable_compile_cache
 from ..kernels import granule_step
 from ..obs import telemetry as _telem
 from ..obs import trace as _trace
@@ -85,16 +86,11 @@ from .recovery import RecoveryController, resolve_on_fault
 from .shmem import RingCorruptionError, RingTimeout, ShmRing, slab_slot_bytes
 from .worker import (
     HB_RECORD_BYTES, HB_RECORD_F64, BatchSpec, BatchedGranuleSim, GranuleSim,
-    GranuleSpec, GroupSpec, TierSpec, configure_compile_cache,
+    GranuleSpec, GroupSpec, TierSpec,
     credit_ring_name, data_ring_name, ext_ring_name, worker_entry,
 )
 
 PyTree = Any
-
-_DEFAULT_CACHE = (
-    os.environ.get("REPRO_PROCS_CACHE_DIR")
-    or os.path.join(tempfile.gettempdir(), "repro_procs_cache")
-)
 
 
 def _worker_mp_context():
@@ -175,7 +171,8 @@ class ProcsEngine:
                 silent worker before declaring it dead.
     prebuild:   AOT-compile each distinct granule signature in-launcher
                 (warming the persistent cache) before any worker spawns.
-    cache_dir:  JAX persistent compilation cache directory (shared).
+    cache_dir:  JAX persistent compilation cache directory (shared);
+                None follows ``core.compile_cache``'s rule.
     batch_signatures:
                 group same-signature granules (``lowering.batch_plan``)
                 into ONE worker process each, stepping the whole group as
@@ -307,7 +304,7 @@ class ProcsEngine:
         self.ring_depth = ring_depth
         self.overlap = granule_step.resolve_overlap(overlap)
         self.timeout = float(timeout)
-        self.cache_dir = cache_dir if cache_dir is not None else _DEFAULT_CACHE
+        self.cache_dir = cache_dir
         self.on_fault = resolve_on_fault(on_fault)
         self.fault_plan = resolve_fault_plan(fault_plan)
         self._incarnation = 0  # bumped on every recovery respawn
@@ -453,7 +450,7 @@ class ProcsEngine:
             "prebuild_seconds": 0.0,
         }
         if prebuild:
-            configure_compile_cache(self.cache_dir)
+            enable_compile_cache(self.cache_dir)
             t0 = time.perf_counter()
             done: set[tuple[str, int]] = set()
             for wspec in self._wspecs:

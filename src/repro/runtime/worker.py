@@ -47,6 +47,7 @@ from typing import Any
 import numpy as np
 
 from ..core import queue as qmod
+from ..core.compile_cache import enable_compile_cache
 from ..core.struct import pytree_dataclass
 from ..obs import telemetry as _telem
 from .fault_tolerance import (
@@ -55,20 +56,6 @@ from .fault_tolerance import (
 from .shmem import RingCorruptionError, RingTimeout, ShmRing, slab_slot_bytes
 
 PyTree = Any
-
-
-def configure_compile_cache(cache_dir: str | None) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (shared by
-    the launcher's prebuild pass and every worker, so each distinct granule
-    signature is compiled once per cache, not once per process)."""
-    if not cache_dir:
-        return
-    import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 # ---------------------------------------------------------------- spec
@@ -383,7 +370,7 @@ class GranuleSim:
         """AOT-compile every stepper this granule's epoch program needs.
 
         ``jit(fn).lower(template).compile()`` — the compiled artifacts land
-        in the JAX persistent compilation cache (``configure_compile_cache``),
+        in the JAX persistent compilation cache (``enable_compile_cache``),
         so the next process with the same signature compiles ~for free.
         Returns {"seconds": total, "n_functions": count}.
         """
@@ -1240,7 +1227,7 @@ def worker_entry(conn, spec_pickle: bytes, worker_index: int,
         sys.stdout = os.fdopen(1, "w", buffering=1)
         sys.stderr = os.fdopen(2, "w", buffering=1)
     try:
-        configure_compile_cache(cache_dir)
+        enable_compile_cache(cache_dir)
         spec = pickle.loads(spec_pickle)
         faults = pickle.loads(faults_pickle) if faults_pickle else ()
         if isinstance(spec, BatchSpec):

@@ -166,6 +166,15 @@ def test_systolic_kernel_vs_oracle_and_matmul(M, R, C, K):
     np.testing.assert_allclose(Y, A @ B, rtol=1e-5)
 
 
+def test_systolic_kernel_refused_on_tpu(monkeypatch):
+    """The register engine's kernel refuses to run natively on a TPU, where
+    its results have not been checked, instead of answering unchecked."""
+    _, _, state = _tile_state(np.random.RandomState(0), 4, 3, 3, 4)
+    monkeypatch.setattr(ops, "_ON_TPU", True)
+    with pytest.raises(NotImplementedError, match="engine='fused'"):
+        ops.systolic_step(state, 4)
+
+
 def test_systolic_kernel_boundary_slabs():
     """West/north slab ingress and east/south egress move packets in order."""
     rng = np.random.RandomState(9)
