@@ -39,12 +39,8 @@ throttled container, is stable enough to gate on.
 The **procs measurement rows** (ISSUE 10) come from the flight
 recorder instead of differencing: each worker's per-phase wall times
 (ingest / step / exchange_issue / exchange_commit / flush / epoch) ride
-the shm telemetry ring to the launcher, fold into the
-``procs.phase.*.s`` histograms, and ``repro.obs.drift`` closes the loop
-against ``core/perfmodel`` — the ``breakdown_procs_drift_*`` rows are
-the relative error between the measured epoch time and the model's
-prediction from the measured phase means (the ``perfmodel.model_drift``
-gauge).
+the shm telemetry ring to the launcher and fold into the
+``procs.phase.*.s`` histograms, read here per epoch.
 """
 import time
 
@@ -172,8 +168,10 @@ from repro.runtime import ProcsEngine
 R = C = 8
 EPOCHS = {epochs}
 
-from repro.obs import drift
 from repro.obs.registry import REGISTRY
+
+PHASES = ('step', 'exchange_issue', 'exchange_commit', 'ingest', 'flush',
+          'epoch')
 
 def run_one(overlap):
     values = (np.arange(R * C) % 7 + 1).astype(np.float32)
@@ -199,21 +197,18 @@ def run_one(overlap):
     eng.set_tracing(False)
     eng.flush_telemetry()
     snap = REGISTRY.snapshot()
-    means = drift.phase_means(snap)
-    fit = drift.compute_drift(snap, overlap=overlap)
     eng.close()
-    return frac, means, fit
+    # seconds per epoch: exchange phases record one sample per tier and
+    # epoch, the others one per epoch
+    hist = lambda p: snap.get(f'procs.phase.{p}.s', {})  # noqa: E731
+    n_ep = max(hist('epoch').get('count', 0), 1)
+    return frac, {p: hist(p).get('sum', 0.0) / n_ep for p in PHASES}
 
 for mode, overlap in (('serial', False), ('overlap', True)):
-    frac, means, fit = run_one(overlap)
+    frac, means = run_one(overlap)
     print(f'PWAIT {mode} {frac:.4f}')
     print(f"PMEAS {mode} " + " ".join(
-        f"{p}={means.get(p, 0.0):.6f}"
-        for p in ('step', 'exchange_issue', 'exchange_commit', 'ingest',
-                  'flush', 'epoch')))
-    if fit:
-        print(f"PDRIFT {mode} {fit['model_drift']:.4f} "
-              f"{fit['predicted_s']:.6f} {fit['measured_s']:.6f}")
+        f"{p}={means[p]:.6f}" for p in PHASES))
 """
 
 
@@ -309,13 +304,6 @@ def bench(smoke: bool = False):
                      f"{float(s) / epoch_s * 100:.0f}% of the "
                      f"{epoch_s * 1e6:.0f} us/epoch {mode} procs epoch "
                      "(telemetry-ring measurement, per-worker mean)")
-        elif line.startswith("PDRIFT"):
-            _, mode, d, pred, meas = line.split()
-            emit(f"breakdown_procs_drift_{mode}", float(d) * 100.0,
-                 f"perfmodel drift {float(d) * 100:.1f}%: measured "
-                 f"{float(meas) * 1e6:.0f} us/epoch vs "
-                 f"{float(pred) * 1e6:.0f} us predicted from the "
-                 f"telemetry phase means ({mode} schedule)")
     for mode, frac in sorted(waits.items()):
         other = waits.get("serial" if mode == "overlap" else "overlap", 0.0)
         emit(f"breakdown_procs_wait_{mode}", frac * 100.0,

@@ -85,6 +85,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import queue as qmod
+from ..obs import trace as _trace
 from ..obs.registry import REGISTRY
 from .block import Block
 from .compat import make_mesh, shard_map
@@ -861,6 +862,7 @@ class GraphEngine:
             return jnp.zeros_like(x)
         return jax.lax.ppermute(x, self.axes, list(perm))
 
+    @jax.named_scope(_trace.PERMUTE)
     def _class_shift(self, x: jax.Array, t: int, rev: bool = False):
         """Move the tier-t slab columns class by class — one ``ppermute``
         per class (each a partial permutation of granules); ``rev`` runs
@@ -871,6 +873,7 @@ class GraphEngine:
             parts.append(self._pshift(x[cl.col0:cl.col0 + cl.cmax], perm))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
+    @jax.named_scope(_trace.PERMUTE)
     def _bat_move(self, x, tbl, t: int, rev: bool = False):
         """The batched slab move: within-device share of every class is a
         ``bat_fwd``/``bat_rev`` batch-row gather instead of a collective;
@@ -892,6 +895,7 @@ class GraphEngine:
             parts.append(part)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
 
+    @jax.named_scope(_trace.DRAIN)
     def _exchange_issue(self, st: GraphState, t: int):
         """Tier t's exchange, ISSUE half: drain every egress queue of the
         tier (credit-bounded) and start the transfer — the forward
@@ -920,6 +924,7 @@ class GraphEngine:
         cnt_in = jnp.where(tb.recv_mask[t], self._class_shift(cnt, t), 0)
         return st.replace(queues=q), (slab_in, cnt_in)
 
+    @jax.named_scope(_trace.FILL)
     def _exchange_commit(self, st: GraphState, t: int, pending) -> GraphState:
         """Tier t's exchange, COMMIT half: land the in-flight slab in the
         ingress queues (ONE bulk ``fill``) and return fresh credits to the
@@ -1183,6 +1188,7 @@ class GraphEngine:
         key = ("until", id(anchor), max_epochs, donate)
         if key not in self._jit_cache:
 
+            @jax.named_scope(_trace.DONE)
             def not_done(s):
                 # Local sum first (covers a (B,)-shaped batched predicate),
                 # then psum over the real mesh axes if there are any.

@@ -48,6 +48,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from . import queue as qmod
+from ..obs import trace as _trace
 from ..obs.registry import REGISTRY
 from .block import Block
 from .distributed import GraphEngine, _dealias_for_donation, _rank_within
@@ -567,6 +568,7 @@ class FusedEngine(GraphEngine):
             self._t6_rows_cache = tuple(rows)
         return self._t6_rows_cache[r]
 
+    @jax.named_scope(_trace.ROWS_SPLIT)
     def _rows_split(self, st: FusedState) -> tuple:
         """Flat local state -> per-row cycle carries.
 
@@ -601,6 +603,7 @@ class FusedEngine(GraphEngine):
             ))
         return tuple(rows)
 
+    @jax.named_scope(_trace.ROWS_JOIN)
     def _rows_join(self, st: FusedState, rows: tuple, credits) -> FusedState:
         """Per-row carries -> the flat local layout (inverse of
         ``_rows_split``; rows run in lockstep so row 0's cycle counter
@@ -622,6 +625,7 @@ class FusedEngine(GraphEngine):
             credits=credits,
         )
 
+    @jax.named_scope(_trace.DRAIN)
     def _rows_exchange_issue(self, rows: tuple, credits, t: int, tb):
         """ISSUE half of the per-row on-device exchange: credit-bounded
         ``stage_drain`` per row, one tiny (B, S_t, E_t, W) slab moved by
@@ -646,6 +650,7 @@ class FusedEngine(GraphEngine):
         cnt_in = jnp.where(rmask, self._bat_move(cnt, bfw, t), 0)
         return tuple(new_rows), (slab_in, cnt_in)
 
+    @jax.named_scope(_trace.FILL)
     def _rows_exchange_commit(self, rows: tuple, credits, t: int, tb,
                               pending):
         """COMMIT half: ``stage_fill`` per row + the ``bat_rev`` credit
@@ -702,86 +707,97 @@ class FusedEngine(GraphEngine):
         # from the compiled body entirely (host-static decision).
         have_q = q.buf.shape[0] > 1
 
-        if have_q:
-            qsize = (q.head - q.tail) % q.capacity
-            qfronts = jnp.take_along_axis(
-                q.buf, q.tail[:, None, None], axis=1
-            )[:, 0, :]
-            # combined channel views: registers first, queue rows after
-            fronts = jnp.concatenate([reg_val_in, qfronts], axis=0)
-            valids = jnp.concatenate([reg_v_in, qsize > 0], axis=0)
-            readies = jnp.concatenate([~reg_v_in, qsize < q.capacity - 1], axis=0)
-        else:
-            fronts, valids, readies = reg_val_in, reg_v_in, ~reg_v_in
+        with jax.named_scope(_trace.READ):
+            if have_q:
+                qsize = (q.head - q.tail) % q.capacity
+                qfronts = jnp.take_along_axis(
+                    q.buf, q.tail[:, None, None], axis=1
+                )[:, 0, :]
+                # combined channel views: registers first, queue rows after
+                fronts = jnp.concatenate([reg_val_in, qfronts], axis=0)
+                valids = jnp.concatenate([reg_v_in, qsize > 0], axis=0)
+                readies = jnp.concatenate(
+                    [~reg_v_in, qsize < q.capacity - 1], axis=0)
+            else:
+                fronts, valids, readies = reg_val_in, reg_v_in, ~reg_v_in
 
         new_states = []
         pay_parts, val_parts, rr_parts = [], [], []
         for gi, grp in enumerate(self.graph.groups):
             blk = grp.block
-            rxm, txm = rx_tbl[gi], tx_tbl[gi]
-            f_all = fronts[rxm]  # (n_slot, n_in, W) — one gather per group
-            v_all = valids[rxm]
-            r_all = readies[txm]
-            rx = {
-                port: (f_all[:, p], v_all[:, p])
-                for p, port in enumerate(blk.in_ports)
-            }
-            tx_ready = {port: r_all[:, p] for p, port in enumerate(blk.out_ports)}
+            with jax.named_scope(_trace.READ):
+                rxm, txm = rx_tbl[gi], tx_tbl[gi]
+                f_all = fronts[rxm]  # (n_slot, n_in, W) — one gather per group
+                v_all = valids[rxm]
+                r_all = readies[txm]
+                rx = {
+                    port: (f_all[:, p], v_all[:, p])
+                    for p, port in enumerate(blk.in_ports)
+                }
+                tx_ready = {port: r_all[:, p]
+                            for p, port in enumerate(blk.out_ports)}
             bst = block_states[gi]
-            new_st, rx_ready, tx = jax.vmap(blk.step)(bst, rx, tx_ready)
+            with jax.named_scope(_trace.STEP):
+                new_st, rx_ready, tx = jax.vmap(blk.step)(bst, rx, tx_ready)
 
-            if blk.clock_divider > 1:
-                en = (cycle % blk.clock_divider) == 0
-                new_st = jax.tree.map(lambda n, o: jnp.where(en, n, o), new_st, bst)
-                rx_ready = {k: v & en for k, v in rx_ready.items()}
-                tx = {k: (p, v & en) for k, (p, v) in tx.items()}
+                if blk.clock_divider > 1:
+                    en = (cycle % blk.clock_divider) == 0
+                    new_st = jax.tree.map(lambda n, o: jnp.where(en, n, o),
+                                          new_st, bst)
+                    rx_ready = {k: v & en for k, v in rx_ready.items()}
+                    tx = {k: (p, v & en) for k, (p, v) in tx.items()}
             new_states.append(new_st)
 
-            if blk.in_ports:
-                rr_parts.append(
-                    jnp.stack([rx_ready[p] for p in blk.in_ports], 1).reshape(-1)
-                )
-            if blk.out_ports:
-                pay_parts.append(
-                    jnp.stack([tx[p][0] for p in blk.out_ports], 1)
-                    .reshape(-1, W).astype(self.dtype)
-                )
-                val_parts.append(
-                    jnp.stack([tx[p][1] for p in blk.out_ports], 1).reshape(-1)
-                )
+            with jax.named_scope(_trace.WRITE):
+                if blk.in_ports:
+                    rr_parts.append(
+                        jnp.stack([rx_ready[p] for p in blk.in_ports], 1)
+                        .reshape(-1)
+                    )
+                if blk.out_ports:
+                    pay_parts.append(
+                        jnp.stack([tx[p][0] for p in blk.out_ports], 1)
+                        .reshape(-1, W).astype(self.dtype)
+                    )
+                    val_parts.append(
+                        jnp.stack([tx[p][1] for p in blk.out_ports], 1)
+                        .reshape(-1)
+                    )
 
         def _cat(parts, empty):
             if not parts:
                 return empty
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
-        pay_all = _cat(pay_parts, jnp.zeros((1, W), self.dtype))
-        val_all = _cat(val_parts, jnp.zeros((1,), bool))
-        rr_all = _cat(rr_parts, jnp.zeros((1,), bool))
+        with jax.named_scope(_trace.WRITE):
+            pay_all = _cat(pay_parts, jnp.zeros((1, W), self.dtype))
+            val_all = _cat(val_parts, jnp.zeros((1,), bool))
+            rr_all = _cat(rr_parts, jnp.zeros((1,), bool))
 
-        # SPSC: the static inverse maps pick each channel's unique producer
-        # and consumer — gathers only, no scatters anywhere in the cycle.
-        # Gather straight into the register/queue halves (no full-width
-        # intermediate to slice).
-        inv_tx_r, inv_rx_r = inv_tx[:n_reg], inv_rx[:n_reg]
+            # SPSC: the static inverse maps pick each channel's unique
+            # producer and consumer — gathers only, no scatters anywhere in
+            # the cycle.  Gather straight into the register/queue halves (no
+            # full-width intermediate to slice).
+            inv_tx_r, inv_rx_r = inv_tx[:n_reg], inv_rx[:n_reg]
 
-        # registers: depth-1 elastic commit (push into empty, pop drains)
-        do_push_r = val_all[inv_tx_r] & inv_tx_mask[:n_reg] & ~reg_v_in
-        do_pop_r = rr_all[inv_rx_r] & inv_rx_mask[:n_reg] & reg_v_in
-        reg_val = jnp.where(do_push_r[:, None], pay_all[inv_tx_r], reg_val_in)
-        reg_v = (reg_v_in & ~do_pop_r) | do_push_r
+            # registers: depth-1 elastic commit (push into empty, pop drains)
+            do_push_r = val_all[inv_tx_r] & inv_tx_mask[:n_reg] & ~reg_v_in
+            do_pop_r = rr_all[inv_rx_r] & inv_rx_mask[:n_reg] & reg_v_in
+            reg_val = jnp.where(do_push_r[:, None], pay_all[inv_tx_r],
+                                reg_val_in)
+            reg_v = (reg_v_in & ~do_pop_r) | do_push_r
 
-        if have_q:
-            # boundary/external queues: the standard ring handshake
-            q2, _, _ = qmod.cycle(
-                q,
-                pay_all[inv_tx[n_reg:]],
-                val_all[inv_tx[n_reg:]] & inv_tx_mask[n_reg:],
-                rr_all[inv_rx[n_reg:]] & inv_rx_mask[n_reg:],
-            )
-        else:
-            q2 = q
-        return (reg_val, reg_v, q2, tuple(new_states), cycle + 1)
+            if have_q:
+                # boundary/external queues: the standard ring handshake
+                q2, _, _ = qmod.cycle(
+                    q,
+                    pay_all[inv_tx[n_reg:]],
+                    val_all[inv_tx[n_reg:]] & inv_tx_mask[n_reg:],
+                    rr_all[inv_rx[n_reg:]] & inv_rx_mask[n_reg:],
+                )
+            else:
+                q2 = q
+            return (reg_val, reg_v, q2, tuple(new_states), cycle + 1)
 
     # ------------------------------------------------------------ fused epoch
     def _inner_cycles(self, st: FusedState, K: int) -> FusedState:
@@ -846,6 +862,7 @@ class FusedEngine(GraphEngine):
         the per-tier credit tuple, which only exchanges touch)."""
         return self._cycle_body(carry[:5], consts[0]) + (carry[5],)
 
+    @jax.named_scope(_trace.DRAIN)
     def _resident_exchange_issue(self, carry, t: int, consts):
         """ISSUE half of tier t's exchange *inside* the resident body.
 
@@ -871,6 +888,7 @@ class FusedEngine(GraphEngine):
                  credits)
         return carry, (slab_in, cnt_in)
 
+    @jax.named_scope(_trace.FILL)
     def _resident_exchange_commit(self, carry, t: int, pending, consts):
         """COMMIT half: ``stage_fill`` the in-flight slab + the ``bat_rev``
         credit return."""

@@ -53,13 +53,21 @@ returns one instance's live state on any engine; ``sim.stats()`` reports
 cycle/epoch plus per-port handshake counters (and the single engine's
 per-channel push/pop counts); ``sim.add_monitor(fn, every=...)`` samples a
 host callback at epoch boundaries during ``run``.
+
+**Spans and counters** (``repro.obs``; DESIGN.md §Observability).  Each
+session boundary is an ``obs.trace.span`` — ``session.run``,
+``session.dispatch`` (one per call of the engine's compiled loop),
+``session.reset``, ``session.read`` (the cycle/epoch reads),
+``session.tx_flush`` and ``session.rx_drain`` — on the profiler's clock,
+so under ``jax.profiler`` they land beside the device's ops.  The same
+boundaries bump the registry counters ``session.dispatches``,
+``session.cycles``, ``session.tx.packets`` and ``session.rx.packets``.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
 import dataclasses
-import time
 import warnings
 from typing import Any, Callable
 
@@ -233,6 +241,11 @@ class Simulation:
         self._rx_ports: dict[str, RxPort] = {}
         self._monitors: list[Monitor] = []
         self._done_cache: dict[int, tuple] = {}  # anchor id -> (ref, jitted)
+        # ``session.cycles`` accounting: the cycle the counted advances
+        # reach, and whether an until-run (whose length only the device
+        # knows) is still uncounted — settled at the next cycle read
+        self._counted_cycle = 0
+        self._uncounted = False
         graph = getattr(engine, "graph", None)
         self._ext_in = dict(graph.ext_in) if graph is not None else {}
         self._ext_out = dict(graph.ext_out) if graph is not None else {}
@@ -262,15 +275,19 @@ class Simulation:
         Extra kwargs go to ``engine.init`` (e.g. ``cell_params=``,
         ``group_params=``).  Distributed states are placed on the mesh.
         """
-        if self.kind == "register":
-            state = self.engine.init(**init_kw)
-        else:
-            if isinstance(key, int):
-                key = jax.random.key(key)
-            state = self.engine.init(key, **init_kw)
-        if hasattr(self.engine, "place"):
-            state = self.engine.place(state)
+        if self._uncounted:
+            _ = self.cycle  # count the old state's until-runs first
+        with _trace.span("session.reset"):
+            if self.kind == "register":
+                state = self.engine.init(**init_kw)
+            else:
+                if isinstance(key, int):
+                    key = jax.random.key(key)
+                state = self.engine.init(key, **init_kw)
+            if hasattr(self.engine, "place"):
+                state = self.engine.place(state)
         self._state = state
+        self._counted_cycle = 0  # every engine's init starts at cycle 0
         for p in self._tx_ports.values():
             p.sent = 0
             p._pending.clear()
@@ -298,16 +315,25 @@ class Simulation:
     @property
     def cycle(self) -> int:
         """Current simulated cycle (identical on every granule at a
-        boundary, which is the only time the host observes it)."""
+        boundary, which is the only time the host observes it).  Waits
+        for the device, and counts into ``session.cycles`` the cycles of
+        until-runs since the last read."""
         st = self._require_state()
-        return int(np.asarray(jax.device_get(st.cycle)).ravel()[0])
+        with _trace.span("session.read"):
+            cyc = int(np.asarray(jax.device_get(st.cycle)).ravel()[0])
+        if self._uncounted:
+            REGISTRY.inc("session.cycles", float(cyc - self._counted_cycle))
+            self._counted_cycle = cyc
+            self._uncounted = False
+        return cyc
 
     @property
     def epoch(self) -> int:
         st = self._require_state()
-        if hasattr(st, "epoch"):
+        if not hasattr(st, "epoch"):
+            return self.cycle // max(self.period, 1)
+        with _trace.span("session.read"):
             return int(np.asarray(jax.device_get(st.epoch)).ravel()[0])
-        return self.cycle // max(self.period, 1)
 
     def block_until_ready(self) -> "Simulation":
         jax.block_until_ready(self._require_state())
@@ -338,18 +364,21 @@ class Simulation:
         st = self._require_state()
         cap = int(self.engine.capacity)
         moved = 0
-        while port._pending:
-            batch = [port._pending[i]
-                     for i in range(min(len(port._pending), cap - 1))]
-            st, n = self.engine.host_push_many(st, port.name, np.stack(batch))
-            n = int(n)
-            for _ in range(n):
-                port._pending.popleft()
-            port.sent += n
-            moved += n
-            if n < len(batch):
-                break  # queue full — the rest waits for the next boundary
+        with _trace.span("session.tx_flush"):
+            while port._pending:
+                batch = [port._pending[i]
+                         for i in range(min(len(port._pending), cap - 1))]
+                st, n = self.engine.host_push_many(st, port.name,
+                                                   np.stack(batch))
+                n = int(n)
+                for _ in range(n):
+                    port._pending.popleft()
+                port.sent += n
+                moved += n
+                if n < len(batch):
+                    break  # queue full — the rest waits for the next boundary
         self._state = st
+        REGISTRY.inc("session.tx.packets", float(moved))
         return moved
 
     def _flush_all_tx(self) -> None:
@@ -363,15 +392,18 @@ class Simulation:
         W = int(self.engine.W if hasattr(self.engine, "W")
                 else self.engine.payload_words)
         out: list[np.ndarray] = []
-        while max_n is None or len(out) < max_n:
-            ask = cap - 1 if max_n is None else min(cap - 1, max_n - len(out))
-            st, pays, cnt = self.engine.host_pop_many(st, port.name, ask)
-            cnt = int(cnt)
-            out.extend(np.asarray(jax.device_get(pays))[:cnt])
-            port.received += cnt
-            if cnt < ask:
-                break
+        with _trace.span("session.rx_drain"):
+            while max_n is None or len(out) < max_n:
+                ask = (cap - 1 if max_n is None
+                       else min(cap - 1, max_n - len(out)))
+                st, pays, cnt = self.engine.host_pop_many(st, port.name, ask)
+                cnt = int(cnt)
+                out.extend(np.asarray(jax.device_get(pays))[:cnt])
+                port.received += cnt
+                if cnt < ask:
+                    break
         self._state = st
+        REGISTRY.inc("session.rx.packets", float(len(out)))
         if not out:
             return np.zeros((0, W), np.float32)
         return np.stack(out)
@@ -415,10 +447,6 @@ class Simulation:
                        for n, p in self._rx_ports.items()},
             },
         }
-        REGISTRY.set("session.tx.sent",
-                     float(sum(p.sent for p in self._tx_ports.values())))
-        REGISTRY.set("session.rx.received",
-                     float(sum(p.received for p in self._rx_ports.values())))
         if self.kind == "single":
             d["detail"] = {
                 "push_count": np.asarray(jax.device_get(st.push_count)),
@@ -482,36 +510,44 @@ class Simulation:
         return mon
 
     # ------------------------------------------------------------------- run
+    def _dispatch(self, call: Callable, cycles: int | None) -> None:
+        """One call of the engine's compiled loop on the owned state (which
+        it donates), as the ``session.dispatch`` span.  The span times the
+        enqueue — JAX returns before the device is done — not the device's
+        work.  ``cycles`` is how far the call advances, or None for an
+        until-run, counted at the next cycle read."""
+        st = self._require_state()
+        with _trace.span("session.dispatch"):
+            self._state = call(st)
+        REGISTRY.inc("session.dispatches")
+        if cycles is None:
+            self._uncounted = True
+        else:
+            REGISTRY.inc("session.cycles", float(cycles))
+            self._counted_cycle += cycles
+
     def _advance_epochs(self, n_epochs: int) -> None:
         """``n_epochs`` boundary periods through the engine's compiled
         loop, donating the owned state."""
         if n_epochs <= 0:
             return
-        st = self._require_state()
-        rec = _trace.recorder()
-        t0 = time.monotonic() if rec.enabled else 0.0
+        n_cycles = n_epochs * self.period
         if self.kind == "single":
-            self._state = self.engine.run(st, n_epochs * self.period,
-                                          donate=True)
+            self._dispatch(
+                lambda st: self.engine.run(st, n_cycles, donate=True),
+                n_cycles)
         else:
             per = self.period // int(self.engine.cycles_per_epoch)
-            self._state = self.engine.run_epochs(st, n_epochs * per,
-                                                 donate=True)
-        REGISTRY.inc("session.epochs", float(n_epochs))
-        if rec.enabled:
-            rec.span("epoch_window", t0, time.monotonic() - t0,
-                     cat="session", args={"epochs": int(n_epochs)})
+            self._dispatch(
+                lambda st: self.engine.run_epochs(st, n_epochs * per,
+                                                  donate=True),
+                n_cycles)
 
     def _advance_cycles_single(self, n_cycles: int) -> None:
         if n_cycles > 0:
-            rec = _trace.recorder()
-            t0 = time.monotonic() if rec.enabled else 0.0
-            self._state = self.engine.run(self._require_state(), n_cycles,
-                                          donate=True)
-            REGISTRY.inc("session.cycles", float(n_cycles))
-            if rec.enabled:
-                rec.span("epoch_window", t0, time.monotonic() - t0,
-                         cat="session", args={"cycles": int(n_cycles)})
+            self._dispatch(
+                lambda st: self.engine.run(st, n_cycles, donate=True),
+                n_cycles)
 
     def _host_done(self, done_fn, cache_key=None) -> bool:
         """Evaluate an engine-view predicate on the host (between chunks).
@@ -584,6 +620,12 @@ class Simulation:
         if (cycles is None) + (epochs is None) + (until is None) < 2:
             raise TypeError("run() takes exactly one of cycles/epochs/until")
         self._require_state()
+        with _trace.span("session.run"):
+            return self._run_body(cycles, epochs, until, max_cycles,
+                                  max_epochs, cache_key)
+
+    def _run_body(self, cycles, epochs, until, max_cycles, max_epochs,
+                  cache_key) -> "Simulation":
         self._flush_all_tx()
 
         if until is not None:
@@ -668,18 +710,15 @@ class Simulation:
         if chunk is None:
             # straight to the engine's compiled while-loop; the budget is
             # relative, so repeated interactive calls share one compilation
-            st = self._require_state()
             if self.kind == "single":
-                self._state = self.engine.run_until(
-                    st, done_fn, max_cycles=max_epochs * per,
-                    cache_key=cache_key, donate=True,
-                )
+                budget = {"max_cycles": max_epochs * per}
             else:
                 per_engine = per // int(self.engine.cycles_per_epoch)
-                self._state = self.engine.run_until(
-                    st, done_fn, max_epochs=max_epochs * per_engine,
-                    cache_key=cache_key, donate=True,
-                )
+                budget = {"max_epochs": max_epochs * per_engine}
+            self._dispatch(
+                lambda st: self.engine.run_until(
+                    st, done_fn, cache_key=cache_key, donate=True, **budget),
+                None)
             return self
         # chunked: cached one-epoch runs + the host-side predicate, checked
         # every epoch — the same cadence as the compiled while-loop, so an
@@ -739,10 +778,13 @@ class Simulation:
                 f"checkpoint was saved from engine "
                 f"{meta['engine_kind']!r}, this session is {self.kind!r}"
             )
+        if self._uncounted:
+            _ = self.cycle  # count the old state's until-runs first
         if gathered:
             self._state = self.engine.scatter_state(self._require_state(), tree)
         else:
             self._state = tree
+        self._counted_cycle = int(meta.get("cycle", 0))
         for n, rec in meta.get("ports", {}).get("tx", {}).items():
             port = self.tx(n)
             port.sent = int(rec.get("sent", 0))

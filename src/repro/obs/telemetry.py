@@ -13,17 +13,19 @@ Record layout (6 little-endian f64, ``TELEM_RECORD_BYTES`` = 48)::
 
     [code, arg, ts, dur, v0, v1]
 
-``ts`` is ``time.monotonic()`` seconds at phase start (CLOCK_MONOTONIC
-is system-wide on Linux, so worker records align with launcher spans),
-``dur`` is the phase wall time in seconds.  ``arg`` and ``v0``/``v1``
-are per-code (see the ``TEV_*`` table below).
+``ts`` is the phase start and ``dur`` its wall time, both nanoseconds on
+the profiler's clock (``trace.now_ns``: system-wide, so worker records
+align with the launcher's spans and a profile's host plane; a float64
+holds today's clock to 256 ns).  ``arg`` and ``v0``/``v1`` are per-code
+(see the ``TEV_*`` table below).
 """
 from __future__ import annotations
 
 import struct
-import time
 
 import numpy as np
+
+from .trace import now_ns
 
 TELEM_RECORD_F64 = 6
 TELEM_RECORD_BYTES = TELEM_RECORD_F64 * 8
@@ -84,10 +86,11 @@ class TelemetryWriter:
         else:
             self.emitted += 1
 
-    def phase(self, code: float, arg: float, t0: float,
+    def phase(self, code: float, arg: float, t0: int,
               v0: float = 0.0, v1: float = 0.0) -> None:
-        """Emit a span record for a phase that started at ``t0``."""
-        self.emit(code, arg, t0, time.monotonic() - t0, v0, v1)
+        """Emit a span record for a phase that started at ``t0``
+        (``now_ns``)."""
+        self.emit(code, arg, t0, now_ns() - t0, v0, v1)
 
 
 def drain(ring, max_records: int = 1 << 20) -> np.ndarray:
@@ -122,9 +125,10 @@ def records_to_events(records: np.ndarray, *, worker: int, pid: int = 0,
             if code == TEV_OCC:
                 registry.observe(f"{prefix}.ring.occupancy", v0)
             else:
-                registry.observe(f"{prefix}.phase.{name}.s", dur)
+                registry.observe(f"{prefix}.phase.{name}.s", dur * 1e-9)
                 if code == TEV_EPOCH:
-                    registry.observe(f"{prefix}.worker.{worker}.epoch.s", dur)
+                    registry.observe(f"{prefix}.worker.{worker}.epoch.s",
+                                     dur * 1e-9)
                     registry.observe(f"{prefix}.worker.{worker}.wait.s", v0)
         if rec_spans and code in _SPAN_CODES:
             args = None
@@ -134,7 +138,7 @@ def records_to_events(records: np.ndarray, *, worker: int, pid: int = 0,
                 args = {"cycles": int(arg)}
             elif code == TEV_EPOCH:
                 args = {"epoch": int(arg), "wait_s": float(v0)}
-            recorder.span(name, float(ts), float(dur), pid=pid, tid=worker,
+            recorder.span(name, int(ts), int(dur), pid=pid, tid=worker,
                           cat="worker", args=args)
     return n
 

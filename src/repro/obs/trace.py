@@ -1,4 +1,23 @@
-"""Structured trace layer: bounded span/instant buffers + Perfetto export.
+"""Structured trace layer: spans on the profiler's clock, a bounded
+recorder with Perfetto export, and the names of the epoch program's device
+scopes.
+
+``span(name)`` is the one span call.  It always enters a
+``jax.profiler.TraceAnnotation`` of the same name, so the span lands in the
+profiler's host plane, beside the device's ops, whenever a profile is
+running; when the recorder is enabled it also appends a Chrome trace-event
+span to the bounded buffer.  With the recorder off and no profile running
+a span costs one flag check and the C++ ``TraceMe`` check.
+
+``now_ns()`` is the profiler's clock: absolute wall-clock nanoseconds
+(``CLOCK_REALTIME``, ~1.8e18 today), which the profiler stamps on its own
+host and device events (its ``.xplane.pb`` file keeps them from the
+profile's start, and that start's absolute time as the Task Environment
+plane's ``profile_start_time``).  Every timestamp the recorder stores is
+on it —
+session spans, instants, recovery snapshots and the procs workers' phase
+records (system-wide, so worker records land on the launcher's
+timeline).  Waits and timeouts stay on ``time.monotonic()``.
 
 Events follow the Chrome trace-event JSON format (loadable in Perfetto /
 ``chrome://tracing``): ``ph="X"`` complete spans with microsecond
@@ -7,23 +26,22 @@ One track per worker / bridge / launcher: ``pid`` groups a host process,
 ``tid`` is the member (worker index, ``NW+i`` for bridge ``i``, and
 ``TID_SESSION`` for the launcher/session track).
 
-Timestamps are ``time.monotonic()`` microseconds — CLOCK_MONOTONIC is
-system-wide on Linux, so spans recorded by worker processes (shipped
-through the shm telemetry ring) land on the same timeline as the
-launcher's own spans.
-
 The recorder is process-global and bounded: past ``max_events`` new
 events are dropped and counted (``trace.dropped`` in the export), never
-grown — a free-running fleet can trace indefinitely.  When disabled
-(default) ``span``/``instant`` return after one flag check.
+grown — a free-running fleet can trace indefinitely.
+
+Device scopes: the epoch program wraps each layer's ops in
+``jax.named_scope`` with one flat name from ``SCOPES``; the name rides in
+every op's ``op_name`` metadata, and a fused op carries its root op's.
 """
 from __future__ import annotations
 
 import atexit
-import contextlib
 import json
 import os
 import time
+
+from jax.profiler import TraceAnnotation
 
 ENV_TRACE = "REPRO_TRACE"
 
@@ -31,6 +49,27 @@ ENV_TRACE = "REPRO_TRACE"
 TID_SESSION = 1000
 
 _PH_ALLOWED = {"X", "i", "M", "C"}
+
+# Device scopes of the epoch program (``jax.named_scope`` names).
+# cycle body (``FusedEngine._cycle_body``)
+READ = "sb.read"        # queue fronts, combined views, rx/tx table gathers
+STEP = "sb.step"        # the vmapped block step + clock-divider masking
+WRITE = "sb.write"      # inverse-map gathers, register commit, queue cycle
+# tier exchange
+DRAIN = "sb.drain"      # credit-bounded egress drain into the slab
+PERMUTE = "sb.permute"  # batch-row moves and ppermutes, both directions
+FILL = "sb.fill"        # ingress fill + the returned-credit read
+# epoch glue
+ROWS_SPLIT = "sb.rows_split"  # flat state -> per-row carries
+ROWS_JOIN = "sb.rows_join"    # per-row carries -> flat state
+DONE = "sb.done"              # run_until's predicate, after every epoch
+SCOPES = (READ, STEP, WRITE, DRAIN, PERMUTE, FILL, ROWS_SPLIT, ROWS_JOIN,
+          DONE)
+
+
+def now_ns() -> int:
+    """The profiler's clock: absolute wall-clock nanoseconds."""
+    return time.time_ns()
 
 
 class TraceRecorder:
@@ -57,44 +96,29 @@ class TraceRecorder:
     def set_track(self, pid: int, tid: int, name: str) -> None:
         self._tracks[(int(pid), int(tid))] = str(name)
 
-    def span(self, name: str, t0: float, dur: float, *, pid: int = 0,
+    def span(self, name: str, t0: int, dur: int, *, pid: int = 0,
              tid: int = TID_SESSION, cat: str = "sim",
              args: dict | None = None) -> None:
-        """One complete span; ``t0`` is monotonic seconds, ``dur`` seconds."""
+        """One complete span measured by the caller: ``t0`` and ``dur`` are
+        nanoseconds on the profiler's clock (``now_ns``)."""
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "X",
-              "ts": t0 * 1e6, "dur": max(dur, 0.0) * 1e6,
+              "ts": t0 / 1e3, "dur": max(dur, 0) / 1e3,
               "pid": int(pid), "tid": int(tid)}
         if args:
             ev["args"] = args
         self._append(ev)
 
     def instant(self, name: str, *, pid: int = 0, tid: int = TID_SESSION,
-                cat: str = "sim", args: dict | None = None,
-                ts: float | None = None) -> None:
+                cat: str = "sim", args: dict | None = None) -> None:
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "i", "s": "p",
-              "ts": (time.monotonic() if ts is None else ts) * 1e6,
-              "pid": int(pid), "tid": int(tid)}
+              "ts": now_ns() / 1e3, "pid": int(pid), "tid": int(tid)}
         if args:
             ev["args"] = args
         self._append(ev)
-
-    @contextlib.contextmanager
-    def span_ctx(self, name: str, *, pid: int = 0, tid: int = TID_SESSION,
-                 cat: str = "sim", args: dict | None = None):
-        """Time the body as one span (no-op when disabled)."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.span(name, t0, time.monotonic() - t0, pid=pid, tid=tid,
-                      cat=cat, args=args)
 
     # -------------------------------------------------------------- export
     def to_dict(self) -> dict:
@@ -136,8 +160,37 @@ def enabled() -> bool:
     return _RECORDER.enabled
 
 
-def span(name: str, t0: float, dur: float, **kw) -> None:
-    _RECORDER.span(name, t0, dur, **kw)
+class span:
+    """The one span call: ``with span("session.dispatch"): ...``.
+
+    Enters a ``TraceAnnotation`` of the same name while a profile is
+    running, and records a complete span into the recorder while it is
+    enabled (``cat``/``args`` go to the recorder only; ``args`` may be set
+    on the span object inside the body).  Otherwise it costs one flag check
+    and the C++ ``TraceMe`` check."""
+
+    __slots__ = ("name", "cat", "args", "_tm", "_t0")
+
+    def __init__(self, name: str, *, cat: str = "session",
+                 args: dict | None = None):
+        self.name = name
+        self.cat = cat
+        self.args = args
+
+    def __enter__(self) -> "span":
+        self._tm = None
+        if TraceAnnotation.is_enabled():
+            self._tm = TraceAnnotation(self.name)
+            self._tm.__enter__()
+        self._t0 = now_ns() if _RECORDER.enabled else None
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._t0 is not None:
+            _RECORDER.span(self.name, self._t0, now_ns() - self._t0,
+                           cat=self.cat, args=self.args)
+        if self._tm is not None:
+            self._tm.__exit__(*exc)
 
 
 def instant(name: str, **kw) -> None:
@@ -181,6 +234,8 @@ def maybe_enable_from_env() -> bool:
 
 
 __all__ = [
-    "ENV_TRACE", "TID_SESSION", "TraceRecorder", "enabled", "instant",
-    "maybe_enable_from_env", "recorder", "span",
+    "DONE", "DRAIN", "ENV_TRACE", "FILL", "PERMUTE", "READ", "ROWS_JOIN",
+    "ROWS_SPLIT", "SCOPES", "STEP", "TID_SESSION", "TraceRecorder", "WRITE",
+    "enabled", "instant", "maybe_enable_from_env", "now_ns", "recorder",
+    "span",
 ]

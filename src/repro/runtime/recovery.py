@@ -231,16 +231,16 @@ class RecoveryController:
                            list(self._inject))
 
     def _take_snapshot(self, state) -> None:
-        t0 = time.monotonic()
-        self._snapshot = self.engine.gather_state(state)
-        self._snapshot_epoch = int(state.epoch)
-        self._absorb_host_io()
-        self.snapshots += 1
-        dur = time.monotonic() - t0
+        with _trace.span("snapshot", cat="recovery") as sp:
+            t0 = time.monotonic()
+            self._snapshot = self.engine.gather_state(state)
+            self._snapshot_epoch = int(state.epoch)
+            self._absorb_host_io()
+            self.snapshots += 1
+            dur = time.monotonic() - t0
+            sp.args = {"epoch": self._snapshot_epoch,
+                       "incarnation": int(self.engine._incarnation)}
         REGISTRY.observe("recovery.snapshot.s", dur)
-        _trace.span("snapshot", t0, dur, cat="recovery",
-                    args={"epoch": self._snapshot_epoch,
-                          "incarnation": int(self.engine._incarnation)})
 
     def _ensure_snapshot(self, state) -> None:
         """Entering a run: make the snapshot describe the CURRENT quiesced

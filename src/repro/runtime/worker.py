@@ -50,6 +50,7 @@ from ..core import queue as qmod
 from ..core.compile_cache import enable_compile_cache
 from ..core.struct import pytree_dataclass
 from ..obs import telemetry as _telem
+from ..obs.trace import now_ns
 from .fault_tolerance import (
     OP_CREDIT_POP, OP_CREDIT_PUSH, OP_SLAB_POP, OP_SLAB_PUSH, encode_blocked,
 )
@@ -845,18 +846,18 @@ class Worker:
     def _traced_epoch(self, tl) -> None:
         """``one_epoch`` with per-phase telemetry records.  Mirrors the
         untraced walk exactly (same ring ops, same op order — traffic
-        stays bit-identical); each phase costs one monotonic read and one
-        non-blocking 48-byte ring push."""
+        stays bit-identical); each phase costs one read of the profiler's
+        clock (``now_ns``) and one non-blocking 48-byte ring push."""
         if self.injector is not None:
             self.injector.before_epoch(self)
         if self.slow_per_epoch:
             time.sleep(self.slow_per_epoch)
         wait0 = self.wait_s
-        e0 = t0 = time.monotonic()
+        e0 = t0 = now_ns()
         self._ingest_ext()
         tl.phase(_telem.TEV_INGEST, 0.0, t0)
         for op, arg in self.sim.program:
-            t0 = time.monotonic()
+            t0 = now_ns()
             if op == "C":
                 self.state = self.sim._compiled[("C", arg)](self.state)
                 tl.phase(_telem.TEV_STEP, float(arg), t0)
@@ -869,10 +870,10 @@ class Worker:
             else:
                 self._exchange_issue(arg)
                 tl.phase(_telem.TEV_ISSUE, float(arg), t0)
-                t0 = time.monotonic()
+                t0 = now_ns()
                 self._exchange_commit(arg)
                 tl.phase(_telem.TEV_COMMIT, float(arg), t0)
-        t0 = time.monotonic()
+        t0 = now_ns()
         self._flush_ext()
         tl.phase(_telem.TEV_FLUSH, 0.0, t0)
         self.state = self.sim._compiled["tick"](self.state)
@@ -882,7 +883,7 @@ class Worker:
             if kind == "d":
                 occ += ring.size()
                 n_d += 1
-        tl.emit(_telem.TEV_OCC, 0.0, time.monotonic(), 0.0,
+        tl.emit(_telem.TEV_OCC, 0.0, now_ns(), 0.0,
                 float(occ), float(n_d))
         tl.phase(_telem.TEV_EPOCH, float(self.epochs_done - 1), e0,
                  v0=self.wait_s - wait0)

@@ -24,17 +24,23 @@ Covered here:
     instant (with incarnation tag) in the exported timeline;
   * a 2-host bridged fleet reports ``connect_s`` separately from the
     steady-state ``wait_fraction`` (the cold-start dilution bugfix);
-  * perfmodel drift arithmetic on a hand-built registry snapshot;
+  * ``run(until=...)`` emits ``session.dispatch`` and counts
+    ``session.cycles``;
+  * every ``sb.*`` device scope is in the op metadata of the fused
+    engine's ``run_until`` program on a wafer-layout torus;
   * ``obs.report`` renders phase breakdown / stragglers / incidents.
 """
 import json
 import os
+import re
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import queue as qmod
-from repro.obs import drift, report as oreport, schema as oschema, telemetry
+from repro.obs import report as oreport, schema as oschema, telemetry
+from repro.obs import trace as otrace
 from repro.obs.registry import REGISTRY, MetricsRegistry
 from repro.obs.trace import TID_SESSION, TraceRecorder
 from repro.runtime import ShmRing
@@ -170,11 +176,11 @@ def test_records_to_events_folds_spans_and_histograms():
     rec = TraceRecorder()
     rec.enabled = True
     reg = MetricsRegistry()
-    rows = np.asarray([
-        [telemetry.TEV_STEP, 32.0, 1.0, 0.010, 0.0, 0.0],
-        [telemetry.TEV_ISSUE, 2.0, 1.011, 0.002, 0.0, 0.0],
-        [telemetry.TEV_EPOCH, 5.0, 1.0, 0.015, 0.004, 0.0],
-        [telemetry.TEV_OCC, 0.0, 1.016, 0.0, 3.0, 2.0],
+    rows = np.asarray([  # ts, dur in ns; the epoch's wait (v0) in s
+        [telemetry.TEV_STEP, 32.0, 1.0e9, 10e6, 0.0, 0.0],
+        [telemetry.TEV_ISSUE, 2.0, 1.011e9, 2e6, 0.0, 0.0],
+        [telemetry.TEV_EPOCH, 5.0, 1.0e9, 15e6, 0.004, 0.0],
+        [telemetry.TEV_OCC, 0.0, 1.016e9, 0.0, 3.0, 2.0],
     ], np.float64)
     n = telemetry.records_to_events(rows, worker=3, pid=0,
                                     recorder=rec, registry=reg)
@@ -184,6 +190,7 @@ def test_records_to_events_folds_spans_and_histograms():
     assert rec.events[0]["args"] == {"cycles": 32}
     assert rec.events[1]["args"] == {"tier": 2}
     assert rec.events[2]["args"] == {"epoch": 5, "wait_s": 0.004}
+    assert (rec.events[0]["ts"], rec.events[0]["dur"]) == (1e6, 1e4)  # us
     snap = reg.snapshot()
     assert snap["procs.phase.step.s"]["count"] == 1
     assert snap["procs.worker.3.epoch.s"]["sum"] == pytest.approx(0.015)
@@ -194,15 +201,15 @@ def test_records_to_events_folds_spans_and_histograms():
 # --------------------------------------------------------- trace recorder
 def test_trace_recorder_export_is_valid_perfetto(tmp_path):
     rec = TraceRecorder()
-    rec.span("ignored", 0.0, 1.0)  # disabled: no-op
+    rec.span("ignored", 0, 1)  # disabled: no-op
     assert rec.events == []
     rec.enabled = True
     rec.set_process(0, "procs:local")
     rec.set_track(0, 0, "worker 0")
     rec.set_track(0, TID_SESSION, "session")
-    rec.span("step", 1.0, 0.5, pid=0, tid=0, cat="worker")
-    with rec.span_ctx("epoch_window", args={"epochs": 2}):
-        pass
+    rec.span("step", 1_000_000_000, 500_000_000, pid=0, tid=0,
+             cat="worker")
+    rec.span("session.dispatch", 2_000_000_000, 1_000, cat="session")
     rec.instant("recovery_incident", cat="recovery", args={"incarnation": 1})
     path = str(tmp_path / "t.json")
     rec.export(path)
@@ -213,7 +220,7 @@ def test_trace_recorder_export_is_valid_perfetto(tmp_path):
         ("process_name", "procs:local"), ("thread_name", "worker 0"),
         ("thread_name", "session")}
     span = next(e for e in evs if e["name"] == "step")
-    assert span["ts"] == 1e6 and span["dur"] == 0.5e6  # seconds -> µs
+    assert span["ts"] == 1e6 and span["dur"] == 0.5e6  # ns -> µs
     assert any(e["ph"] == "i" and e["name"] == "recovery_incident"
                for e in evs)
     assert doc["otherData"]["dropped"] == 0
@@ -223,7 +230,7 @@ def test_trace_recorder_bounded_buffer():
     rec = TraceRecorder(max_events=5)
     rec.enabled = True
     for i in range(9):
-        rec.span(f"s{i}", float(i), 0.1)
+        rec.span(f"s{i}", i, 100)
     assert len(rec.events) == 5
     assert rec.dropped == 4
     rec.clear()
@@ -306,7 +313,8 @@ def test_traced_bit_identical_in_process(tmp_path):
             np.testing.assert_array_equal(a, b,
                                           err_msg=f"{name} boundary {step}")
         doc = oschema.validate_trace_file(str(tmp_path / f"{name}.json"))
-        assert any(e["name"] == "epoch_window" for e in doc["traceEvents"])
+        assert any(e["name"] == "session.dispatch"
+                   for e in doc["traceEvents"])
 
 
 def test_procs_trace_per_worker_spans_bit_identical(closing, tmp_path):
@@ -400,46 +408,65 @@ def test_bridged_fleet_connect_vs_wait(closing, tmp_path):
         np.testing.assert_array_equal(a, b, err_msg=f"boundary {step}")
 
 
-# -------------------------------------------------------- perfmodel drift
-def _phase_snapshot(step, issue_sum, commit_sum, ingest, flush, epoch,
-                    n_epochs=4, n_tiers=2):
-    reg = MetricsRegistry()
-    for _ in range(n_epochs):
-        reg.observe("procs.phase.step.s", step)
-        reg.observe("procs.phase.ingest.s", ingest)
-        reg.observe("procs.phase.flush.s", flush)
-        reg.observe("procs.phase.epoch.s", epoch)
-        for _ in range(n_tiers):
-            reg.observe("procs.phase.exchange_issue.s",
-                        issue_sum / (n_epochs * n_tiers))
-            reg.observe("procs.phase.exchange_commit.s",
-                        commit_sum / (n_epochs * n_tiers))
-    return reg.snapshot()
+# ------------------------------------------- session spans, device scopes
+def test_run_until_records_dispatch_and_cycles():
+    """``run(until=...)`` goes straight to the engine's compiled
+    while-loop: one ``session.dispatch`` span per call, and the cycles it
+    ran counted into ``session.cycles`` at the next cycle read."""
+    sim = _sessions_k1()["fused"]
+    sim.reset(0)
+    before = REGISTRY.snapshot()
+    rec = otrace.recorder()
+    prev, n0 = rec.enabled, len(rec.events)
+    rec.enabled = True
+    try:
+        sim.run(until=lambda s: s.cycle >= 5, cache_key="obs.until5")
+        sim.run(until=lambda s: s.cycle >= 9, cache_key="obs.until9")
+        assert sim.cycle == 9
+        names = [e["name"] for e in rec.events[n0:]]
+    finally:
+        rec.enabled = prev
+        del rec.events[n0:]
+    assert names.count("session.dispatch") == 2
+    assert names.count("session.run") == 2
+    assert names.count("session.read") == 1
+    after = sim.stats()["metrics"]
+    delta = lambda k: after[k] - before.get(k, 0.0)  # noqa: E731
+    assert delta("session.dispatches") == 2
+    assert delta("session.cycles") == 9
 
 
-def test_compute_drift_serial_arithmetic():
-    snap = _phase_snapshot(step=0.010, issue_sum=0.008, commit_sum=0.004,
-                           ingest=0.001, flush=0.0005, epoch=0.016)
-    reg = MetricsRegistry()
-    out = drift.compute_drift(snap, overlap=False, registry=reg)
-    assert out["t_step"] == pytest.approx(0.010)
-    # comm phases divide their sample SUM by epochs (one sample per
-    # tier*epoch), so 8 issue + 8 commit samples fold to per-epoch cost
-    assert out["t_comm"] == pytest.approx((0.008 + 0.004) / 4)
-    assert out["t_residual"] == pytest.approx(0.0015)
-    assert out["predicted_s"] == pytest.approx(0.010 + 0.003 + 0.0015)
-    assert out["model_drift"] == pytest.approx(
-        abs(0.016 - 0.0145) / 0.016)
-    assert reg.snapshot()["perfmodel.model_drift"] == \
-        pytest.approx(out["model_drift"])
+def test_fused_run_until_program_carries_every_scope():
+    """The fused engine's ``run_until`` program on a 16x16 torus with
+    ``wafer_64k``'s tiles and tiers, every granule axis folded as batch
+    rows (so the tier exchange and the per-row split/join are compiled):
+    every ``sb.*`` scope names ops in its HLO's op metadata."""
+    from repro.core import (ChannelGraph, FusedEngine, fold_mesh,
+                            tiered_grid_partition)
+    from repro.hw.manycore import (ManycoreCell, allreduce_done,
+                                   make_core_params)
 
+    R = C = 16
+    graph = ChannelGraph.torus(
+        ManycoreCell(R, C), R, C,
+        params=make_core_params(np.ones((R, C), np.float32)), capacity=62)
+    mesh, batch = fold_mesh({"pod": 2, "gr": 2, "gc": 2}, jax.devices()[:1])
+    assert set(batch) == {"pod", "gr", "gc"}
+    eng = FusedEngine(graph, tiered_grid_partition(R, C, [(2, 1), (2, 2)]),
+                      mesh, tiers=[(("pod",), 4), (("gr", "gc"), 16)],
+                      batch_axes=batch)
+    state = eng.place(eng.init(jax.random.key(0)))
 
-def test_compute_drift_overlap_and_empty():
-    assert drift.compute_drift({}) == {}
-    snap = _phase_snapshot(step=0.010, issue_sum=0.008, commit_sum=0.004,
-                           ingest=0.0, flush=0.0, epoch=0.012)
-    out = drift.compute_drift(snap, overlap=True)
-    assert out["predicted_s"] == pytest.approx(max(0.010, 0.003))
+    def done(s):
+        return allreduce_done(s.block_states[0], s.tables.active[0])
+
+    lowered = jax.jit(
+        lambda st: eng.run_until(st, done, 1, donate=False)).lower(state)
+    hlo = lowered.compiler_ir("hlo").get_hlo_module().to_string()
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in otrace.SCOPES:
+        assert any(re.search(rf"(^|/){re.escape(scope)}(/|$)", p)
+                   for p in paths), scope
 
 
 # ---------------------------------------------------------------- report
