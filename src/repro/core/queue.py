@@ -121,12 +121,22 @@ def cycle(
     same cycle — SPSC push touches ``head``, pop touches ``tail``, so they
     commute, exactly as in the shared-memory implementation.
 
+    The push is one dense select over the capacity axis, not a batched
+    dynamic update: XLA:TPU lowers the latter (and any scatter) to a
+    scalar loop with one trip per queue.
+
     Returns (new_queues, did_push, did_pop).
     """
     do_push = push_valid & ~full(q)
     do_pop = pop_ready & ~empty(q)
 
-    buf = jax.vmap(_push_one)(q.buf, q.head, push_payload, do_push)
+    slot = (jnp.arange(q.capacity, dtype=q.head.dtype)[None, :, None]
+            == q.head[:, None, None])
+    buf = jnp.where(
+        slot & do_push[:, None, None],
+        push_payload[:, None, :].astype(q.buf.dtype),
+        q.buf,
+    )
     head = jnp.where(do_push, (q.head + 1) % q.capacity, q.head)
     tail = jnp.where(do_pop, (q.tail + 1) % q.capacity, q.tail)
     return q.replace(buf=buf, head=head, tail=tail), do_push, do_pop

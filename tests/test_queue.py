@@ -1,9 +1,11 @@
-"""Queue semantics vs a Python deque oracle (paper §III-B), property-based."""
+"""Queue semantics vs a Python deque oracle (paper §III-B), property-based,
+and `cycle` vs a per-queue NumPy reference, bit for bit."""
 import collections
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from _hypothesis_compat import given, settings, st
 
@@ -99,3 +101,96 @@ def test_batched_queues_independent():
     pv = jnp.array([True, False, True, False])
     q, ok, _ = qmod.cycle(q, jnp.arange(4.0).reshape(4, 1), pv, jnp.zeros(4, bool))
     np.testing.assert_array_equal(np.asarray(qmod.size(q)), [1, 0, 1, 0])
+
+
+def _ref_cycle(buf, head, tail, cap, payload, valid, ready):
+    """Per-queue reference of ``qmod.cycle`` in plain NumPy."""
+    buf, head, tail = buf.copy(), head.copy(), tail.copy()
+    n = head.shape[0]
+    did_push = np.zeros(n, bool)
+    did_pop = np.zeros(n, bool)
+    for i in range(n):
+        h, t = int(head[i]), int(tail[i])
+        if valid[i] and (h + 1) % cap != t:
+            buf[i, h] = payload[i]
+            head[i] = (h + 1) % cap
+            did_push[i] = True
+        if ready[i] and h != t:
+            tail[i] = (t + 1) % cap
+            did_pop[i] = True
+    return buf, head, tail, did_push, did_pop
+
+
+_CASES = ["random", "full", "empty", "wrap", "push_pop"]
+
+
+def _start(rng, case, n, cap, W):
+    """Initial (buf, head, tail) and first-cycle (valid, ready) masks."""
+    buf = rng.standard_normal((n, cap, W)).astype(np.float32)
+    tail = rng.randint(0, cap, n).astype(np.int32)
+    valid, ready = rng.rand(n) < 0.5, rng.rand(n) < 0.5
+    if case == "random":
+        size = rng.randint(0, cap, n)
+    elif case == "full":  # every push refused
+        size, valid = np.full(n, cap - 1), np.ones(n, bool)
+    elif case == "empty":  # every pop refused
+        size, ready = np.zeros(n, int), np.ones(n, bool)
+    elif case == "wrap":  # head at capacity-1, pushes wrap to slot 0
+        size = rng.randint(0, cap - 1, n)
+        tail = ((cap - 1 - size) % cap).astype(np.int32)
+        valid = np.ones(n, bool)
+    else:  # "push_pop": both handshakes asked in one cycle
+        size = rng.randint(1, max(cap - 1, 2), n)
+        valid, ready = np.ones(n, bool), np.ones(n, bool)
+    head = ((tail + size) % cap).astype(np.int32)
+    return buf, head, tail, valid, ready
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["flat", "vmap"])
+@pytest.mark.parametrize("case", _CASES)
+@pytest.mark.parametrize("W", [1, 2])
+@pytest.mark.parametrize("cap", [2, 8, 62])
+def test_cycle_matches_numpy_reference(cap, W, case, batched):
+    """``cycle`` equals a per-queue NumPy ring, bit for bit, over a seeded
+    sequence of push/pop masks — flat, and under ``jax.vmap`` over a batch
+    axis as ``GraphEngine`` calls it."""
+    rng = np.random.RandomState(cap * 100 + W * 10 + _CASES.index(case))
+    B, n, steps = (3 if batched else 1), 5, 2 * cap + 4
+    starts = [_start(rng, case, n, cap, W) for _ in range(B)]
+    buf, head, tail = (np.stack([s[k] for s in starts]) for k in range(3))
+    valid0, ready0 = (np.stack([s[k] for s in starts]) for k in (3, 4))
+    step = qmod.cycle
+    if batched:
+        step = jax.vmap(step)
+    step = jax.jit(step)
+
+    def squeeze(x):
+        return x if batched else x[0]
+
+    q = qmod.QueueArray(buf=jnp.asarray(squeeze(buf)),
+                        head=jnp.asarray(squeeze(head)),
+                        tail=jnp.asarray(squeeze(tail)), capacity=cap)
+    for s in range(steps):
+        payload = rng.standard_normal((B, n, W)).astype(np.float32)
+        if s == 0:
+            valid, ready = valid0, ready0
+        else:
+            valid, ready = rng.rand(B, n) < 0.6, rng.rand(B, n) < 0.4
+        want = [_ref_cycle(buf[b], head[b], tail[b], cap, payload[b],
+                           valid[b], ready[b]) for b in range(B)]
+        buf, head, tail, did_push, did_pop = (
+            np.stack([w[k] for w in want]) for k in range(5))
+        q, got_push, got_pop = step(
+            q, jnp.asarray(squeeze(payload)), jnp.asarray(squeeze(valid)),
+            jnp.asarray(squeeze(ready)))
+        for got, ref in ((q.buf, buf), (q.head, head), (q.tail, tail),
+                         (got_push, did_push), (got_pop, did_pop)):
+            np.testing.assert_array_equal(np.asarray(got), squeeze(ref))
+        if s == 0 and case == "full":
+            assert not did_push.any()
+        if s == 0 and case == "empty":
+            assert not did_pop.any()
+        if s == 0 and case == "wrap":
+            assert did_push.all() and (head == 0).all()
+        if s == 0 and case == "push_pop" and cap > 2:
+            assert did_push.all() and did_pop.all()

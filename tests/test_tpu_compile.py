@@ -6,7 +6,8 @@ compiler refuses, a kernel layout it cannot lower, a program that does not
 fit.  Each compiles the main path's device code at its real size:
 
   * the fused engine's epoch body for one WAFER granule (8,192 cores) in
-    the mode ``fuse="auto"`` resolves to;
+    the mode ``fuse="auto"`` resolves to, and that body at WAFER's and the
+    benchmark's queue sizes holds no loop but the epoch loop;
   * the same body as a resident Pallas kernel — refused by the chip's
     compiler, which is why ``auto`` never picks it (a TPU run with
     ``fuse="pallas"`` fails at compile time instead of falling back);
@@ -15,7 +16,9 @@ fit.  Each compiles the main path's device code at its real size:
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,24 +63,30 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def wafer_row():
-    """The WAFER fused engine as ``chip_smoke.py`` builds it on one chip,
-    and the shapes of one granule's cycle carry (batch row 0)."""
-    R, C = WAFER.grid_rows, WAFER.grid_cols
+def _granule_row(cfg):
+    """The fused engine as ``chip_smoke.py`` builds it on one chip, at
+    ``cfg``'s sizes, and the shapes of one granule's cycle carry (batch
+    row 0)."""
+    R, C = cfg.grid_rows, cfg.grid_cols
     vals = np.ones((R, C), np.float32)
     graph = ChannelGraph.torus(
         ManycoreCell(R, C), R, C, params=make_core_params(vals),
-        capacity=WAFER.queue_capacity)
+        capacity=cfg.queue_capacity)
     mesh, batch = fold_mesh({"pod": 2, "gr": 2, "gc": 2}, jax.devices()[:1])
     eng = FusedEngine(
         graph, tiered_grid_partition(R, C, [(2, 1), (2, 2)]), mesh,
-        tiers=[(("pod",), WAFER.k_outer), (("gr", "gc"), WAFER.k_inner)],
+        tiers=[(("pod",), cfg.k_outer), (("gr", "gc"), cfg.k_inner)],
         batch_axes=batch)
     state = jax.eval_shape(eng.init, jax.random.key(0))
     rows = jax.eval_shape(
         lambda s: eng._rows_split(eng._local_view(s)), state)
     return eng, rows[0]
+
+
+@pytest.fixture(scope="module")
+def wafer_row():
+    """The WAFER fused engine and one granule's cycle carry."""
+    return _granule_row(WAFER)
 
 
 def _on(sharding, tree):
@@ -86,9 +95,9 @@ def _on(sharding, tree):
         tree)
 
 
-def _epoch_body(eng, mode):
+def _epoch_body(eng, mode, k=WAFER.k_inner):
     return jax.jit(lambda c: granule_step.epoch_loop(
-        eng._cycle_body, c, WAFER.k_inner, consts=eng._t6_row(0),
+        eng._cycle_body, c, k, consts=eng._t6_row(0),
         mode=mode, interpret=False))
 
 
@@ -103,6 +112,25 @@ def test_fused_wafer_granule_epoch_compiles(one_chip, wafer_row, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+# The benchmark's ``wafer_64k`` sizes: WAFER's grid at the paper's queue
+# depth and inner sync period.
+CELL = dataclasses.replace(WAFER, queue_capacity=62, k_inner=16)
+
+
+@pytest.mark.parametrize("cfg", [WAFER, CELL], ids=["wafer", "cell"])
+def test_fused_wafer_epoch_has_no_queue_loop(one_chip, cfg):
+    """The compiled epoch body holds one loop, the epoch loop itself: the
+    queue push is a dense select, not a scatter that XLA:TPU would lower
+    to a scalar loop with one trip per queue row, every cycle."""
+    eng, row = _granule_row(cfg)
+    text = _epoch_body(eng, "xla", cfg.k_inner).lower(
+        _on(one_chip, row)).compile().as_text()
+    assert len(re.findall(r"\swhile\(", text)) == 1
+    scatters = [n for n in re.findall(r'op_name="([^"]*)"', text)
+                if n.endswith("/scatter")]
+    assert scatters == []
 
 
 def test_fused_wafer_granule_pallas_body_refused(one_chip, wafer_row):
