@@ -716,6 +716,31 @@ class GraphEngine:
         while f > 0 and not self.tier_classes[f - 1]:
             f -= 1
         self._fold_from = f
+        self._publish_ici_plan()
+
+    def _publish_ici_plan(self) -> None:
+        """Publish, per tier t, what one exchange moves between devices:
+        gauges ``exchange.ici_permutes.<t>`` (the ``ppermute``s it runs:
+        slab and count forward, credit back, for each class that leaves
+        its device) and ``exchange.ici_bytes.<t>`` (the bytes those
+        move, every device pair's whole column window, padding rows
+        included).  Both read 0 when every class stays on its device."""
+        packet = jnp.dtype(self.dtype).itemsize * self.W
+        for t, cls_t in enumerate(self.tier_classes):
+            # a slot's packets, then its int32 count and int32 credit
+            slot = self.E_tiers[t] * packet + 4 + 4
+            permutes = nbytes = 0
+            for cl in cls_t:
+                # unbatched, the class's own granule permutation is the
+                # collective, one granule per device
+                perm, rows = ((cl.perm, 1) if cl.real_perm is None
+                              else (cl.real_perm, self.B))
+                if not perm:
+                    continue
+                permutes += 3
+                nbytes += len(perm) * rows * cl.cmax * slot
+            REGISTRY.set(f"exchange.ici_permutes.{t}", permutes)
+            REGISTRY.set(f"exchange.ici_bytes.{t}", nbytes)
 
     def _dev(self, arr: np.ndarray) -> jax.Array:
         """(G, ...) host table -> (dev_shape..., ...) device array."""
