@@ -59,6 +59,26 @@ from ..kernels import granule_step
 PyTree = Any
 
 
+def _rows(pay: jax.Array, flags: jax.Array, fdt) -> Any:
+    """Payload rows ``(n, W)`` and flag columns ``(n, k)`` as the port
+    gathers move them: one ``(n, W + k)`` table when the payload dtype is
+    the 32-bit flag dtype ``fdt``, else the payload beside an ``fdt`` flag
+    array."""
+    flags = flags.astype(fdt)
+    if pay.dtype == flags.dtype:
+        return jnp.concatenate([pay, flags], axis=-1)
+    return pay, flags
+
+
+def _take(rows: Any, idx: jax.Array, W: int) -> tuple:
+    """``rows[idx]`` (a ``_rows`` table) split back into (payload, flag
+    columns)."""
+    if isinstance(rows, tuple):
+        return rows[0][idx], rows[1][idx]
+    g = rows[idx]
+    return g[..., :W], g[..., W:]
+
+
 @pytree_dataclass
 class FusedTables:
     """Fused-engine lookup tables (device-varying, constant over time).
@@ -66,8 +86,8 @@ class FusedTables:
     Extends the ``GraphTables`` port/exchange tables with the *inverse*
     port maps: because channels are SPSC, every combined channel id has at
     most one local producer and one local consumer, so the per-cycle
-    drive/commit step is three static **gathers** (producer payload,
-    producer valid, consumer ready) instead of scatters — the XLA-CPU/TPU
+    drive/commit step is static **gathers** (producer payload with its
+    valid flag, consumer ready) instead of scatters — the XLA-CPU/TPU
     friendly formulation.
     """
 
@@ -706,6 +726,12 @@ class FusedEngine(GraphEngine):
         # has no boundary/external channels, so the queue machinery vanishes
         # from the compiled body entirely (host-static decision).
         have_q = q.buf.shape[0] > 1
+        # No flag is gathered as ``pred``: XLA:TPU packs four to a 32-bit
+        # word and gathers them one element at a time.  Valid and ready
+        # flags move as 0/1 in a 32-bit dtype instead — the payload's own,
+        # as extra columns of the payload rows, where that is 32-bit.
+        fdt = jnp.dtype(self.dtype if jnp.dtype(self.dtype).itemsize == 4
+                        else jnp.int32)
 
         with jax.named_scope(_trace.READ):
             if have_q:
@@ -720,6 +746,8 @@ class FusedEngine(GraphEngine):
                     [~reg_v_in, qsize < q.capacity - 1], axis=0)
             else:
                 fronts, valids, readies = reg_val_in, reg_v_in, ~reg_v_in
+            # one per-channel table: [fronts | valid | ready]
+            chans = _rows(fronts, jnp.stack([valids, readies], 1), fdt)
 
         new_states = []
         pay_parts, val_parts, rr_parts = [], [], []
@@ -727,9 +755,10 @@ class FusedEngine(GraphEngine):
             blk = grp.block
             with jax.named_scope(_trace.READ):
                 rxm, txm = rx_tbl[gi], tx_tbl[gi]
-                f_all = fronts[rxm]  # (n_slot, n_in, W) — one gather per group
-                v_all = valids[rxm]
-                r_all = readies[txm]
+                # (n_slot, n_in, W) fronts and their valids: one gather
+                f_all, fl = _take(chans, rxm, W)
+                v_all = fl[..., 0] != 0
+                r_all = _take(chans, txm, W)[1][..., 1] != 0
                 rx = {
                     port: (f_all[:, p], v_all[:, p])
                     for p, port in enumerate(blk.in_ports)
@@ -749,9 +778,12 @@ class FusedEngine(GraphEngine):
             new_states.append(new_st)
 
             with jax.named_scope(_trace.WRITE):
+                # flags leave the block as 32-bit per port, before the
+                # stack, so no ``pred`` array is relaid out either
                 if blk.in_ports:
                     rr_parts.append(
-                        jnp.stack([rx_ready[p] for p in blk.in_ports], 1)
+                        jnp.stack([rx_ready[p].astype(fdt)
+                                   for p in blk.in_ports], 1)
                         .reshape(-1)
                     )
                 if blk.out_ports:
@@ -760,8 +792,9 @@ class FusedEngine(GraphEngine):
                         .reshape(-1, W).astype(self.dtype)
                     )
                     val_parts.append(
-                        jnp.stack([tx[p][1] for p in blk.out_ports], 1)
-                        .reshape(-1)
+                        jnp.stack([tx[p][1].astype(fdt)
+                                   for p in blk.out_ports], 1)
+                        .reshape(-1, 1)
                     )
 
         def _cat(parts, empty):
@@ -770,9 +803,12 @@ class FusedEngine(GraphEngine):
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
         with jax.named_scope(_trace.WRITE):
-            pay_all = _cat(pay_parts, jnp.zeros((1, W), self.dtype))
-            val_all = _cat(val_parts, jnp.zeros((1,), bool))
-            rr_all = _cat(rr_parts, jnp.zeros((1,), bool))
+            # [payload | valid] per producer port; ready per consumer port
+            txs = _rows(
+                _cat(pay_parts, jnp.zeros((1, W), self.dtype)),
+                _cat(val_parts, jnp.zeros((1, 1), fdt)), fdt,
+            )
+            rr_all = _cat(rr_parts, jnp.zeros((1,), fdt))
 
             # SPSC: the static inverse maps pick each channel's unique
             # producer and consumer — gathers only, no scatters anywhere in
@@ -781,19 +817,20 @@ class FusedEngine(GraphEngine):
             inv_tx_r, inv_rx_r = inv_tx[:n_reg], inv_rx[:n_reg]
 
             # registers: depth-1 elastic commit (push into empty, pop drains)
-            do_push_r = val_all[inv_tx_r] & inv_tx_mask[:n_reg] & ~reg_v_in
-            do_pop_r = rr_all[inv_rx_r] & inv_rx_mask[:n_reg] & reg_v_in
-            reg_val = jnp.where(do_push_r[:, None], pay_all[inv_tx_r],
-                                reg_val_in)
+            pay_r, val_r = _take(txs, inv_tx_r, W)
+            do_push_r = (val_r[:, 0] != 0) & inv_tx_mask[:n_reg] & ~reg_v_in
+            do_pop_r = (rr_all[inv_rx_r] != 0) & inv_rx_mask[:n_reg] & reg_v_in
+            reg_val = jnp.where(do_push_r[:, None], pay_r, reg_val_in)
             reg_v = (reg_v_in & ~do_pop_r) | do_push_r
 
             if have_q:
                 # boundary/external queues: the standard ring handshake
+                pay_q, val_q = _take(txs, inv_tx[n_reg:], W)
                 q2, _, _ = qmod.cycle(
                     q,
-                    pay_all[inv_tx[n_reg:]],
-                    val_all[inv_tx[n_reg:]] & inv_tx_mask[n_reg:],
-                    rr_all[inv_rx[n_reg:]] & inv_rx_mask[n_reg:],
+                    pay_q,
+                    (val_q[:, 0] != 0) & inv_tx_mask[n_reg:],
+                    (rr_all[inv_rx[n_reg:]] != 0) & inv_rx_mask[n_reg:],
                 )
             else:
                 q2 = q

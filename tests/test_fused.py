@@ -149,6 +149,51 @@ def test_fused_matches_netlist_chain(k_epoch):
         assert int(ref.group_state(rs, i).count) == int(eng.group_state(es, i).count) == 3
 
 
+@pytest.mark.parametrize("k_epoch", [1, 16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32, jnp.bfloat16],
+                         ids=["float32", "int32", "bfloat16"])
+def test_fused_matches_netlist_chain_payload_dtype(dtype, k_epoch):
+    """Valid/ready flags cross the port gathers as columns of the payload
+    rows (32-bit payloads) or as a separate int32 array (any other): both
+    forms keep the chain bit-exact vs the netlist, registers and queues."""
+    from test_graph import Increment
+
+    def chain():
+        net = Network(payload_words=2, capacity=8, dtype=dtype)
+        insts = [net.instantiate(Increment(), name=f"b{i}") for i in range(3)]
+        net.external_in(insts[0]["to_rtl"], "tx")
+        for a, b in zip(insts, insts[1:]):
+            net.connect(a["from_rtl"], b["to_rtl"])
+        net.external_out(insts[-1]["from_rtl"], "rx")
+        return net
+
+    ref = chain().build()
+    eng = chain().build(
+        engine="fused", mesh=make_mesh((1,), ("gx",)), K=k_epoch
+    )
+    rs = ref.init(jax.random.key(0))
+    es = eng.init(jax.random.key(0))
+    sent = [[-7, 5], [0, -1], [96, 3]]
+    for v in sent:
+        rs, ok1 = ref.push_external(rs, "tx", jnp.array(v, dtype))
+        es, ok2 = eng.push_external(es, "tx", jnp.array(v, dtype))
+        assert bool(ok1) and bool(ok2)
+    rs = ref.run(rs, 48)
+    es = eng.run_epochs(es, -(-48 // k_epoch))
+    for v in sent:
+        rs, p1, v1 = ref.pop_external(rs, "rx")
+        es, p2, v2 = eng.pop_external(es, "rx")
+        assert bool(v1) and bool(v2)
+        assert p1.dtype == p2.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
+        np.testing.assert_array_equal(
+            np.asarray(p2, np.float32), [v[0] + 3, v[1]])
+    es, _, v3 = eng.pop_external(es, "rx")
+    assert not bool(v3)
+    for i in range(3):
+        assert int(ref.group_state(rs, i).count) == int(eng.group_state(es, i).count) == 3
+
+
 @pytest.mark.parametrize("k_epoch", [1, 4])
 def test_fused_matches_netlist_hetero_analog(k_epoch):
     """The hetero SoC (RTL + SW + rate-controlled analog blocks): K=1 is

@@ -45,7 +45,7 @@ class Increment(Block):
         return (
             state.replace(count=state.count + fire.astype(jnp.int32)),
             {"to_rtl": fire},
-            {"from_rtl": (pay.at[0].add(1.0), fire)},
+            {"from_rtl": (pay.at[0].add(1), fire)},
         )
 
 

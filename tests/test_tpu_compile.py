@@ -8,6 +8,7 @@ fit.  Each compiles the main path's device code at its real size:
   * the fused engine's epoch body for one WAFER granule (8,192 cores) in
     the mode ``fuse="auto"`` resolves to, and that body at WAFER's and the
     benchmark's queue sizes holds no loop but the epoch loop;
+  * that body moves only 32-bit elements through its gathers;
   * the same body as a resident Pallas kernel — refused by the chip's
     compiler, which is why ``auto`` never picks it (a TPU run with
     ``fuse="pallas"`` fails at compile time instead of falling back);
@@ -131,6 +132,20 @@ def test_fused_wafer_epoch_has_no_queue_loop(one_chip, cfg):
     scatters = [n for n in re.findall(r'op_name="([^"]*)"', text)
                 if n.endswith("/scatter")]
     assert scatters == []
+
+
+@pytest.mark.parametrize("cfg", [WAFER, CELL], ids=["wafer", "cell"])
+def test_fused_wafer_epoch_gathers_are_32_bit(one_chip, cfg):
+    """Every gather in the compiled epoch body moves 32-bit elements: the
+    valid/ready flags ride the payload row gathers as 32-bit columns.
+    XLA:TPU packs narrower elements (``pred`` four to a word) and gathers
+    them one element at a time."""
+    eng, row = _granule_row(cfg)
+    text = _epoch_body(eng, "xla", cfg.k_inner).lower(
+        _on(one_chip, row)).compile().as_text()
+    kinds = re.findall(r"=\s*(\w+)\[[^\]]*\]\S*\s+gather\(", text)
+    assert kinds, "no gather found in the compiled body"
+    assert set(kinds) <= {"f32", "s32", "u32"}, sorted(set(kinds))
 
 
 def test_fused_wafer_granule_pallas_body_refused(one_chip, wafer_row):
