@@ -5,10 +5,8 @@
 
 Phases (one process; designs and host traffic come from ``--seed``):
 
-  1. Device and setup: JAX version, devices, compile-cache directory, and
-     the fused engine's resolved epoch mode and Pallas ``interpret`` flag.
-     Exits non-zero before any work unless JAX sees a TPU and the fused
-     engine runs natively on it.
+  1. Device and setup: JAX version, devices and compile-cache directory.
+     Exits non-zero before any work unless JAX sees a TPU.
   2. The flagship wafer (``configs.manycore.WAFER``: a 256x256 torus of
      ``ManycoreCell``, 65,536 cores) on ``engine="fused"``, its 2 pods x
      (2x2) granules folded onto one chip as batch rows, running the
@@ -59,7 +57,6 @@ from repro.hw.manycore import (  # noqa: E402
     ManycoreCell, allreduce_done, expected_total, make_core_params,
 )
 from repro.hw.pipestage import make_chain  # noqa: E402
-from repro.kernels import granule_step  # noqa: E402
 
 # Granule layouts of the wafer: axis sizes (outermost first) and the
 # nested block split of ``tiered_grid_partition``.  The one-chip layout is
@@ -244,17 +241,11 @@ def main(argv=None) -> int:
 
     # ---- phase 1: device and setup
     devs = jax.devices()
-    mode = granule_step.resolve_mode("auto")
-    interpret = granule_step.resolve_interpret("auto")
     print(f"jax {jax.__version__}; devices {devs}")
-    print(f"device_kind {devs[0].device_kind!r}; fused epoch mode {mode!r}, "
-          f"interpret {interpret}")
+    print(f"device_kind {devs[0].device_kind!r}")
     if devs[0].platform != "tpu":
         print(f"no TPU: JAX sees platform {devs[0].platform!r}",
               file=sys.stderr)
-        return 2
-    if interpret:
-        print("the fused engine would run in interpret mode", file=sys.stderr)
         return 2
     if len(devs) < args.chips:
         print(f"--chips {args.chips} needs {args.chips} devices, JAX sees "

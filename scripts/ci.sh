@@ -1,48 +1,29 @@
 #!/usr/bin/env bash
-# Per-PR gate: tier-1 tests + smoke benchmarks + every example in smoke mode.
+# CPU gate: tier-1 tests, the runtime and fleet drills, the flight
+# recorder, and every example in smoke mode.  Speed is measured on the
+# chip only (chipbench/, cells declared in BENCHMARK.json); no stage here
+# times anything.
 #
 #   scripts/ci.sh            # everything
 #   scripts/ci.sh tests      # tier-1 tests only
-#   scripts/ci.sh smoke      # smoke benchmarks only
 #   scripts/ci.sh procs      # multiprocess-runtime smoke (hard timeout)
-#   scripts/ci.sh fleet      # 2-launcher TCP-bridged fleet smoke (ISSUE 9)
-#   scripts/ci.sh obs        # flight-recorder smoke + overhead gates (ISSUE 10)
+#   scripts/ci.sh fleet      # 2-launcher TCP-bridged fleet smoke
+#   scripts/ci.sh obs        # flight-recorder smoke: traced run + its suite
 #   scripts/ci.sh examples   # all examples, smoke-sized, via the session API
 #
-# The smoke benchmarks run every suite (all four engines, the batched
-# tiered exchange, the subprocess multi-device paths) on a tiny cycle
-# budget, so engine regressions are caught per-PR even where the full
-# benchmark numbers would take too long.  BENCH_*.json summaries are
-# validated against the repro-bench-v1 schema by ``benchmarks.schema``,
-# which also enforces the perf gates:
-#   * compiled single-netlist backend >= interpreted reference
-#     (asserted inside benchmarks.backend_speedup AND re-checked from the
-#     JSON rows — the PR 2 "0x speedup" regression can't come back);
-#   * FusedEngine >= GraphEngine on the smoke wafer hot-loop config, and
-#     within collective-noise tolerance on the distributed smoke config;
-#   * signature-batched stepping >= the unbatched FusedEngine on the smoke
-#     wafer, and the cycles/s/core metric is recorded (ISSUE 6);
-#   * the split issue/commit (overlapped) exchange stays within noise of
-#     the serial schedule on the smoke wafer, and the receive-late procs
-#     fleet never waits longer than the strict serial fleet (ISSUE 7; the
-#     >=1x overlap win, the procs wait-fraction drop, and the <=30%
-#     perfmodel overlap fit are gated on the committed BENCH_PR8.json);
-#   * the self-healing fleet stays affordable (ISSUE 8): recover-mode
-#     fault-free runs <= 1.5x raise-mode, warm respawn <= 0.7x a cold
-#     build+launch, and the kill-drill MTTR rows are recorded.  The
-#     procs stage additionally runs the fault drills themselves (kill ->
-#     bit-identical recovery, stall -> FleetStallError) under a hard
-#     timeout, plus an env-knob drill (REPRO_ON_FAULT/REPRO_FAULT_PLAN)
-#     through a real example;
-#   * the multi-host fleet stays honest (ISSUE 9): the 2-launcher
-#     TCP-bridged chain keeps >= 0.5x single-host throughput with
-#     bit-exactness asserted in-benchmark (gated on the committed
-#     BENCH_PR9.json), and the fleet stage drills the bridge framing,
-#     loopback bit-exactness, and link-kill recovery under hard timeouts;
-#   * the flight recorder stays ~free (ISSUE 10): registry-disabled
-#     dispatch <= 1.02x, fully-traced 4-worker fleet <= 1.10x, and the
-#     obs stage additionally runs a REPRO_TRACE smoke wafer, validates
-#     the exported Perfetto trace, and renders the text report.
+# Stages:
+#   * tests: the tier-1 pytest run;
+#   * procs: the free-running fleet on a smoke wafer (plain, batched with
+#     the overlapped exchange), its fault drills (kill -> bit-identical
+#     recovery, stall -> FleetStallError) and an env-knob drill
+#     (REPRO_ON_FAULT/REPRO_FAULT_PLAN) through a real example, each
+#     under a hard timeout;
+#   * fleet: the 2-launcher TCP-bridged fleet — framing, loopback
+#     bit-exactness, link-kill recovery and the 2-pod tiered wafer across
+#     the bridge, each under a hard timeout;
+#   * obs: a REPRO_TRACE smoke wafer whose exported Perfetto trace is
+#     validated and rendered, and the obs test suite;
+#   * examples: every example through the Simulation session API.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -57,30 +38,6 @@ if [[ "$stage" == "all" || "$stage" == "tests" ]]; then
     fi
     echo "=== tier-1 tests ==="
     python -m pytest -x -q
-fi
-
-if [[ "$stage" == "all" || "$stage" == "smoke" ]]; then
-    echo "=== fused-engine smoke suite ==="
-    python -m pytest -q tests/test_fused.py \
-        -k "modes or contract or lowering or chain or capacity"
-    echo "=== signature-batched smoke suite ==="
-    python -m pytest -q tests/test_batched.py \
-        -k "plan or env or perfmodel or epochs"
-    echo "=== pallas-interpret smoke (multi-epoch body via env override) ==="
-    REPRO_EPOCH_MODE=pallas REPRO_PALLAS_INTERPRET=1 \
-        python -m pytest -q tests/test_batched.py -k "env or epochs"
-    echo "=== smoke benchmarks (incl. tiered wafer-scale + engines) ==="
-    python -m benchmarks.run --smoke --json BENCH_SMOKE.json
-    echo "=== BENCH json schema + perf gates (benchmarks.schema) ==="
-    python -m benchmarks.schema BENCH_SMOKE.json --gates smoke
-    python -m benchmarks.schema BENCH_PR8.json --gates trajectory
-    python -m benchmarks.schema BENCH_PR9.json --gates fleet
-    python -m benchmarks.schema BENCH_PR10.json --gates obs
-    # every committed trajectory file must validate AND embed its
-    # predecessor's rows as baseline (the PR-over-PR audit chain)
-    for f in BENCH_PR*.json; do
-        python -m benchmarks.schema "$f"
-    done
 fi
 
 if [[ "$stage" == "all" || "$stage" == "procs" ]]; then
@@ -140,11 +97,10 @@ if [[ "$stage" == "all" || "$stage" == "obs" ]]; then
     # ISSUE 10: the flight recorder end to end — a procs smoke wafer run
     # traced via the REPRO_TRACE env knob must export a Perfetto-loadable
     # timeline (validated by repro.obs.schema, rendered by
-    # repro.obs.report), the obs test suite must pass (bit-identical
+    # repro.obs.report), and the obs test suite must pass (bit-identical
     # traced-vs-untraced traffic on every engine, recovery incidents in
-    # the timeline), and the overhead ratios must hold their gates.
+    # the timeline).
     OBS_TRACE="${TMPDIR:-/tmp}/repro_ci_trace.json"
-    OBS_BENCH="${TMPDIR:-/tmp}/BENCH_OBS_SMOKE.json"
     echo "=== flight recorder: traced smoke wafer (REPRO_TRACE) ==="
     REPRO_TRACE="$OBS_TRACE" timeout 300 python examples/wafer_scale.py \
         --rows 8 --cols 8 --k-inner 4 --engine procs
@@ -153,9 +109,6 @@ if [[ "$stage" == "all" || "$stage" == "obs" ]]; then
     python -m repro.obs.report "$OBS_TRACE" --top 5
     echo "=== flight recorder: obs test suite (hard 600s timeout) ==="
     timeout 600 python -m pytest -q tests/test_obs.py -x
-    echo "=== flight recorder: overhead gates (<=1.02x off, <=1.10x on) ==="
-    python -m benchmarks.run --only obs_overhead --smoke --json "$OBS_BENCH"
-    python -m benchmarks.schema "$OBS_BENCH" --gates obs
 fi
 
 if [[ "$stage" == "all" || "$stage" == "examples" ]]; then

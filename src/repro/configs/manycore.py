@@ -3,15 +3,14 @@
 A 1024x1024 grid of systolic MAC cores (the PicoRV32 array's dataflow)
 computing Y = A @ B for M=1024 streamed rows, distributed over the
 production mesh with the epoch-batched queue engine.  This config is
-exercised by launch/dryrun.py --arch manycore and by the benchmarks;
-it is not part of the 40 LM cells.
+exercised by launch/dryrun.py --arch manycore; it is not part of the 40
+LM cells.
 
 The sync rates are tiered (DESIGN.md §3): intra-pod (ICI) boundaries
 exchange every ``k_inner`` cycles, inter-pod (DCI) boundaries every
 ``k_inner * k_outer`` — the paper's fast-shm/slow-TCP split.  The flat
 single-K schedule is ``k_outer = 1``.  ``WAFER`` is the CPU-runnable
-flagship shape consumed by ``examples/wafer_scale.py``
-(``benchmarks/wafer_scale.py`` sweeps schedules around it).
+flagship shape consumed by ``examples/wafer_scale.py``.
 """
 import dataclasses
 

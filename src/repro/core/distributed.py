@@ -473,8 +473,8 @@ class GraphEngine:
                window ``w+1``, and issue/commit touch disjoint queue rows
                (egress vs ingress) and per-tier credit windows.  "auto"
                (default off) — the ``REPRO_OVERLAP`` env var overrides
-               auto, an explicit bool overrides both (the ``resolve_mode``
-               precedence from PR 6).
+               auto, an explicit bool overrides both
+               (``granule_step.resolve_overlap``).
     """
 
     engine_kind = "graph"
@@ -1029,7 +1029,7 @@ class GraphEngine:
 
     def _inner_cycles(self, st: GraphState, K: int) -> GraphState:
         """K granule-local cycles — the innermost hot loop.  ``FusedEngine``
-        overrides this with the fused-epoch kernel."""
+        overrides this with the fused epoch body."""
         cyc = (jax.vmap(self._local_cycle) if self._batched
                else self._local_cycle)
         return jax.lax.scan(
@@ -1126,7 +1126,8 @@ class GraphEngine:
 
     # ------------------------------------------------------------------ run
     def epoch_fn(self):
-        """shard_map'd single-epoch function (used by dryrun + benchmarks)."""
+        """shard_map'd single-epoch function (used by dryrun and the
+        compile rehearsals)."""
 
         def run(state):
             return self._global_view(self._epoch(self._local_view(state)))

@@ -3,8 +3,7 @@ the fused-backend family (§Perf).
 
 The general fused fast path is ``core.fused.FusedEngine``: it lowers ANY
 partitioned channel graph to depth-1 register channels + a fused K-cycle
-epoch, and subsumes this engine — on XLA:CPU it now measures *faster*
-than this preset (BENCH_PR3 ``engine_speedup``).  What this preset keeps
+epoch, and subsumes this engine.  What this preset keeps
 is the hand-written Pallas kernel that fuses the MAC *block semantics*
 (not just the channel plumbing) for TPU.  Use ``engine="fused"`` for
 arbitrary topologies; ``engine="register"`` remains the systolic-grid

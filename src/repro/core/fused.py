@@ -1,4 +1,4 @@
-"""Fused-epoch engine — the kernel-fused fast path for ANY channel graph
+"""Fused-epoch engine — the fused fast path for ANY channel graph
 (§Perf; the generalization of ``fastgrid`` promised by DESIGN.md).
 
 ``GraphEngine`` interprets a granule cycle over deep SPSC queues: every
@@ -18,9 +18,8 @@ epoch instead:
     protocol are *bit-identical* to ``GraphEngine`` — the two engines
     interoperate with the same sync schedule and the same partition tree;
   * the whole ``K_inner``-cycle tier-inner epoch executes as ONE fused
-    body (``kernels.granule_step.epoch_loop``): fully unrolled straight-
-    line XLA for small K, a ``fori_loop`` for large K, or one Pallas
-    kernel with the granule state resident in VMEM on TPU.
+    body (``kernels.granule_step.epoch_loop``): a ``fori_loop`` of the
+    cycle body inside the epoch's one jitted XLA computation.
 
 Correctness contract (property-tested in ``tests/test_fused.py``):
 
@@ -131,24 +130,13 @@ class FusedState:
 class FusedEngine(GraphEngine):
     """Fused-epoch distributed engine over an arbitrary partitioned graph.
 
-    Accepts everything ``GraphEngine`` accepts, plus:
-
-    fuse:    epoch-body strategy — "auto" (the ``fori_loop`` body on every
-             backend; overridable via the ``REPRO_EPOCH_MODE`` env var),
-             or explicitly "xla" | "unroll" | "pallas" (see
-             ``kernels.granule_step``; "pallas" does not compile for a
-             TPU and fails there at compile time).
-    pallas_interpret: run the Pallas path in interpret mode.  "auto"
-             (default) interprets off-TPU, so ``fuse="pallas"`` is live
-             on CPU CI; on a TPU only an explicit ``True`` interprets
-             (off-TPU ``REPRO_PALLAS_INTERPRET`` overrides the argument).
-    batch_axes: signature batching — see ``GraphEngine``.  On the fused
-             engine a batched granule axis additionally unlocks the
-             *resident multi-epoch kernel*: every tier whose exchanges
-             stay on-device (trailing batched tiers) folds into the fused
-             epoch body, so one dispatch — one ``pallas_call`` under
-             ``fuse="pallas"`` — runs the whole K_outer x K_inner span
-             with registers, queues and credits resident.
+    Accepts everything ``GraphEngine`` accepts.  ``batch_axes``
+    (signature batching — see ``GraphEngine``) additionally unlocks the
+    *resident multi-epoch program* here: every tier whose exchanges stay
+    on-device (trailing batched tiers) folds into the fused epoch body,
+    so one ``epoch_program`` — one traced XLA body — runs the whole
+    K_outer x K_inner span with registers, queues and credits in its
+    carry.
     """
 
     engine_kind = "fused"
@@ -162,13 +150,9 @@ class FusedEngine(GraphEngine):
         axes: Sequence[str] | None = None,
         tiers: Sequence | None = None,
         *,
-        fuse: str = "auto",
-        pallas_interpret: Any = "auto",
         batch_axes=None,
         overlap: Any = "auto",
     ):
-        self.fuse = fuse
-        self.pallas_interpret = pallas_interpret
         super().__init__(
             graph, partition, mesh, K=K, axes=axes, tiers=tiers,
             batch_axes=batch_axes, overlap=overlap,
@@ -177,7 +161,7 @@ class FusedEngine(GraphEngine):
         # First tier index from which EVERY exchange is on-device (batched
         # classes with an empty real_perm; exchange-free tiers trivially
         # qualify): tiers [_resident_from:] run as ONE epoch_program — the
-        # resident multi-epoch kernel.  Unbatched engines keep the plain
+        # resident multi-epoch program.  Unbatched engines keep the plain
         # fold region (real_perm is None there, never ()).
         r = len(self.tiers)
         while r > 0 and all(
@@ -445,7 +429,7 @@ class FusedEngine(GraphEngine):
     @staticmethod
     def _tables6(tb: FusedTables):
         """The (loop-invariant) table leaves the cycle body actually reads —
-        passed to the epoch kernel as read-only consts, NOT loop carry."""
+        passed to the epoch body as read-only consts, NOT loop carry."""
         return (
             tb.rx_idx, tb.tx_idx,
             tb.inv_tx, tb.inv_tx_mask, tb.inv_rx, tb.inv_rx_mask,
@@ -713,8 +697,8 @@ class FusedEngine(GraphEngine):
         ``GraphEngine._local_cycle`` — fronts, valids and readies are all
         taken before any block steps — with channel storage split between
         the register file and the small boundary queue array.  Pure in
-        its explicit arguments (no captured engine state), so the epoch
-        kernel can run it inside ``pallas_call``.
+        its explicit arguments (no captured engine state), so one body
+        serves ``epoch_loop``, ``epoch_program`` and the per-row walk.
         """
         reg_val_in, reg_v_in, q, block_states, cycle = carry
         rx_tbl, tx_tbl, inv_tx, inv_tx_mask, inv_rx, inv_rx_mask = tables6
@@ -842,7 +826,7 @@ class FusedEngine(GraphEngine):
 
         Only the mutating leaves ride the loop carry; port tables enter as
         read-only consts, and the exchange tables/credits/epoch counter
-        never touch the kernel at all.  Batched engines step the whole
+        never enter the loop at all.  Batched engines step the whole
         granule batch in this same single dispatch (flat layout).
         """
         carry = (st.reg_val, st.reg_v, st.queues, st.block_states, st.cycle)
@@ -852,14 +836,13 @@ class FusedEngine(GraphEngine):
         out = granule_step.epoch_loop(
             self._cycle_body, carry, K,
             consts=self._tables6(st.tables),
-            mode=self.fuse, interpret=self.pallas_interpret,
         )
         return st.replace(
             reg_val=out[0], reg_v=out[1], queues=out[2],
             block_states=out[3], cycle=out[4],
         )
 
-    # -------------------------------------------- resident multi-epoch kernel
+    # ------------------------------------------- resident multi-epoch program
     def _resident_program(self, t0: int) -> tuple:
         """The ("C", n)/("X", t) op list realizing tiers [t0:] — the same
         recursion as ``_tier_round``, flattened so the whole span executes
@@ -867,8 +850,7 @@ class FusedEngine(GraphEngine):
         exchange-free tiers elided).  Under ``overlap`` every boundary's
         run of ("X", t) ops is rewritten to all-issues-then-all-commits
         (``granule_step.overlap_program``) so transfers are in flight
-        across the sync point — inside the pallas lowering that is the
-        double-buffered DMA staging."""
+        across the sync point."""
         if t0 not in self._program_cache:
 
             def prog(t):
@@ -906,10 +888,9 @@ class FusedEngine(GraphEngine):
         Every class of a resident tier has an empty ``real_perm`` (that is
         what admitted it), so the issue is slab staging on the local fused
         queue rows: credit-bounded ``stage_drain`` into the
-        (B, S_t, E_t, W) slab + the ``bat_fwd`` batch-row gather.  Under
-        ``fuse="pallas"`` the returned pending pair is what the kernel
-        parks in the double-buffered VMEM staging slots (async DMA started
-        at issue, waited at commit)."""
+        (B, S_t, E_t, W) slab + the ``bat_fwd`` batch-row gather.  The
+        returned pending pair rides ``epoch_program``'s walk as plain
+        values until the tier's commit."""
         reg_val, reg_v, q, block_states, cycle, credits = carry
         sidx, smask, _, rmask, bfw, _ = (x[t] for x in consts[1])
         q = self._q_batch_view(q)  # flat rows -> (B, n_q) for the slab move
@@ -943,9 +924,7 @@ class FusedEngine(GraphEngine):
 
     def _resident_exchange(self, carry, t: int, consts):
         """Tier t's serial exchange inside the resident body — commit∘issue
-        (see the halves above); under ``fuse="pallas"`` this runs between
-        the kernel's in-VMEM epoch loops, the slab never leaves the
-        kernel."""
+        (see the halves above), run between the program's epoch loops."""
         carry, pending = self._resident_exchange_issue(carry, t, consts)
         return self._resident_exchange_commit(carry, t, pending, consts)
 
@@ -964,7 +943,6 @@ class FusedEngine(GraphEngine):
                     granule_step.epoch_loop(
                         self._cycle_body, c_r, arg,
                         consts=self._t6_row(r),
-                        mode=self.fuse, interpret=self.pallas_interpret,
                     )
                     for r, c_r in enumerate(rows)
                 )
@@ -1023,9 +1001,10 @@ class FusedEngine(GraphEngine):
 
     def _tier_round(self, st: FusedState, t: int) -> FusedState:
         """Batched engines run every all-on-device span of the tier tree
-        resident — registers, queues and credits never leave the kernel
-        between its inner epochs and tier boundaries — falling back to the
-        inherited loop-and-exchange recursion above ``_resident_from``.
+        resident — registers, queues and credits stay in one traced body's
+        carry across its inner epochs and tier boundaries — falling back
+        to the inherited loop-and-exchange recursion above
+        ``_resident_from``.
 
         Pure-batch engines (every mesh axis a batch axis) take the per-row
         blocked walk: each ("C", n) op runs every row's n-cycle window as
@@ -1056,7 +1035,6 @@ class FusedEngine(GraphEngine):
             issue_fn=self._resident_exchange_issue,
             commit_fn=self._resident_exchange_commit,
             consts=consts,
-            mode=self.fuse, interpret=self.pallas_interpret,
         )
         return st.replace(
             reg_val=out[0], reg_v=out[1], queues=out[2],
@@ -1065,9 +1043,9 @@ class FusedEngine(GraphEngine):
 
     def _pend_tiers(self, t0: int) -> tuple:
         """Resident spans commit their own split exchanges inside the
-        ``epoch_program`` (pallas pendings live in kernel-local staging
-        buffers and cannot cross the kernel boundary), so they contribute
-        nothing to the caller's pending chain."""
+        ``epoch_program`` (its op list pairs every issue with a commit
+        before the program ends — ``validate_program``), so they
+        contribute nothing to the caller's pending chain."""
         if self._batched and t0 >= self._resident_from:
             return ()
         return super()._pend_tiers(t0)

@@ -158,10 +158,9 @@ class Network:
                               bit-identical pipelining of tier transfers
                               with compute; "auto"/bool, REPRO_OVERLAP
                               env override — DESIGN.md §Perf).
-        engine="fused"     -> fused.FusedEngine — the kernel-fused fast
+        engine="fused"     -> fused.FusedEngine — the fused-epoch fast
                               path for arbitrary topologies (§Perf):
-                              same kwargs as "graph" plus fuse /
-                              pallas_interpret (epoch-body strategy).
+                              same kwargs as "graph".
         engine="register"  -> fastgrid.RegisterGridEngine (systolic-grid
                               networks only); kwargs: mesh, K.
         engine="procs"     -> runtime.launcher.ProcsEngine — the free-
@@ -203,16 +202,9 @@ class Network:
         if engine in ("graph", "fused"):
             if engine == "graph":
                 from .distributed import GraphEngine as Engine
-
-                extra = {}
             else:
                 from .fused import FusedEngine as Engine
-
-                extra = {
-                    k: kw.pop(k)
-                    for k in ("fuse", "pallas_interpret")
-                    if k in kw
-                }
+            extra = {}
             mesh = kw.pop("mesh")
             K = kw.pop("K", 1)
             tiers = kw.pop("tiers", None)

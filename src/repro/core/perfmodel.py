@@ -16,8 +16,8 @@ controller only achieves this in expectation).  The T_comm nonideality maps
 to the epoch length K: a packet crossing a granule boundary waits up to K
 cycles, so for a round trip ``T_comm ≈ K / F_wall`` and the error term
 ``2*T_comm*F_A_wall`` becomes ``≈ 2*K`` cycles per boundary crossing — a
-*bound*, not a distribution.  ``benchmarks/accuracy_vs_rate.py`` sweeps K to
-reproduce Fig. 15.
+*bound*, not a distribution.  ``examples/systolic_matmul.py`` sweeps K
+(the accuracy-vs-rate table of Fig. 15).
 """
 from __future__ import annotations
 
@@ -171,10 +171,9 @@ def fit_dispatch_overhead(
     """Recover ``(t_step, t_dispatch)`` from ONE measured A/B pair.
 
     Inverts the two-equation model ``t_unbatched = B*(t_disp + t_step)``,
-    ``t_batched = t_disp + B*t_step`` (pad = 1) — the fit
-    ``benchmarks/run.py`` applies to the wafer rows to validate the model
-    against a second, differently-shaped measured pair.  Degenerate
-    measurements (batched slower than unbatched) clamp to t_disp = 0."""
+    ``t_batched = t_disp + B*t_step`` (pad = 1); validate it against a
+    second, differently-shaped measured pair.  Degenerate measurements
+    (batched slower than unbatched) clamp to t_disp = 0."""
     if batch < 2:
         raise ValueError("need batch >= 2 to separate t_step from t_dispatch")
     t_disp = max((t_unbatched - t_batched) / (batch - 1), 0.0)
@@ -249,13 +248,12 @@ def fit_overlap_residual(t_step: float, t_comm: float,
     the serial run's phase split (step vs drain+transfer+fill).
 
     Inverts ``t_meas = max(t_step, t_comm) + residual``; clamped at 0 for
-    a measurement faster than the model floor (timer noise).  The fit
-    ``benchmarks/run.py`` applies: fit the residual on one wafer row,
-    predict the other rows' overlapped times with it, and report the
-    worst relative error (the acceptance gate is <= 15%) — the residual
-    absorbs whatever fraction of the exchange the backend's scheduler
-    failed to hide, so the VALIDATED claim is that the residual is a
-    stable per-configuration constant, not that overlap is perfect."""
+    a measurement faster than the model floor (timer noise).  Fit the
+    residual on one configuration and predict the others' overlapped
+    times with it — the residual absorbs whatever fraction of the
+    exchange the backend's scheduler failed to hide, so the model's claim
+    is that the residual is a stable per-configuration constant, not
+    that overlap is perfect."""
     return max(t_overlapped_meas - max(t_step, t_comm), 0.0)
 
 

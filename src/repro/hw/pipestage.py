@@ -3,9 +3,8 @@
 The simplest useful Block: forward the inbound packet, adding ``delta``
 to word 0, under a full ready/valid handshake.  One block type, arbitrary
 chain/ring topologies — the unit cell for host-I/O scenarios, the
-engine-parity benchmarks, and the multiprocess runtime's build-time
-suite (its workers unpickle the block by reference, so it lives in the
-package, not in a script).
+engine-parity tests, and the multiprocess runtime (its workers unpickle
+the block by reference, so it lives in the package, not in a script).
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Step builders shared by dryrun.py, train.py and benchmarks.
+"""Step builders shared by dryrun.py and train.py.
 
 Builds jitted train/prefill/decode steps for an (arch config, shape,
 mesh, strategy) cell, with all in/out shardings resolved from

@@ -7,8 +7,7 @@ Counters, gauges and histograms under stable dotted names
 
 Cost model: publishing is a dict lookup plus a float add.  With the
 registry *disabled* every ``inc``/``set``/``observe`` is a single
-attribute check and an immediate return — the ≤1.02x tracing-off budget
-of ISSUE 10 (gated by ``benchmarks/obs_overhead.py``).
+attribute check and an immediate return.
 """
 from __future__ import annotations
 

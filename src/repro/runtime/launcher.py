@@ -24,8 +24,7 @@ AOT-compiles one granule simulator per *distinct granule signature*
 (``jit(...).lower().compile()`` into the shared JAX persistent
 compilation cache).  Workers then compile against a warm cache, so build
 time grows with unique granule shapes — O(#block kinds), not
-O(#instances) — the paper's flat-build-time property, measured in
-``benchmarks/procs_runtime.py``.
+O(#instances) — the paper's flat-build-time property.
 
 **Failure surface** (``runtime.fault_tolerance``): every reply wait polls
 worker exitcodes (ANY exit while replies are pending, clean or not) and

@@ -54,7 +54,7 @@ the journaled pushes are *re-injected* into their external rings exactly
 when the replay reaches the boundary where the host originally pushed
 them, keeping replayed ingress cycle-identical.
 
-**MTTR model** (measured in ``benchmarks/fault_recovery.py``)::
+**MTTR model**::
 
     MTTR ≈ detect + backoff + respawn(warm) + restore + replay
     detect  ~ heartbeat timeout (kill: one poll interval via exitcode)

@@ -22,8 +22,7 @@ counts, queue counts, tier rates.  N instances of the same block shape
 therefore trace to the SAME jaxpr, the launcher compiles each distinct
 signature once, and every worker's own compile is a hit in the JAX
 persistent compilation cache — build time grows with *unique* granule
-shapes, not with instance count (benchmarked in
-``benchmarks/procs_runtime.py``).
+shapes, not with instance count.
 
 Exchange protocol per boundary channel (bit-identical to the engines'
 credit protocol, DESIGN.md §3): at the channel's tier cadence the sender

@@ -1,8 +1,8 @@
 """Shared test fixtures.
 
 NOTE: XLA_FLAGS / device-count overrides are deliberately NOT set here —
-smoke tests and benchmarks must see the real single CPU device.  Tests that
-need a multi-device mesh spawn a subprocess (see test_distributed.py).
+tests must see the real single CPU device.  Tests that need a
+multi-device mesh spawn a subprocess (see test_distributed.py).
 """
 import os
 import sys

@@ -24,7 +24,7 @@ from repro.core.compat import make_mesh
 from repro.core.distributed import GraphEngine
 from repro.core import perfmodel
 from repro.hw.manycore import ManycoreCell, make_core_params
-from repro.kernels import granule_step
+from test_fused import assert_allreduce_matches_netlist
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 EXAMPLES = os.path.join(os.path.dirname(__file__), "..", "examples")
@@ -230,68 +230,18 @@ def test_batched_soc_analog_k1_capacity2():
         np.asarray(got.results), np.asarray(truth.results))
 
 
-# -------------------------------------------- resident body: pallas vs xla
+# ------------------------------------------ resident body vs single netlist
 def test_batched_resident_body_pallas_vs_xla_bit_identical():
-    """The per-row resident body compiles to the same trajectory under
-    fuse='pallas' (interpret) and fuse='xla' — the kernel path is a
-    lowering choice, not a semantics fork."""
+    """The per-row resident body (pure-batch rows, serial exchange) ends
+    the allreduce where the single netlist does, core for core."""
     R, C = 8, 4
     vals = (np.arange(R * C) % 11 + 1).astype(np.float32).reshape(R, C)
     mesh = make_mesh((1,), ("gx",))
     part = np.arange(R * C) % 2
     kw = dict(tiers=[(("gx",), 4)], batch_axes={"gx": 2})
-    ref = FusedEngine(_torus(R, C, vals, 4), part, mesh, fuse="xla", **kw)
-    pal = FusedEngine(_torus(R, C, vals, 4), part, mesh, fuse="pallas",
-                      pallas_interpret=True, **kw)
-    rs = ref.run_epochs(ref.place(ref.init(jax.random.key(0))), 4,
-                        donate=False)
-    ps = pal.run_epochs(pal.place(pal.init(jax.random.key(0))), 4,
-                        donate=False)
-    for a, b in zip(jax.tree.leaves(jax.device_get(rs)),
-                    jax.tree.leaves(jax.device_get(ps))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# --------------------------------------------------- env-resolved mode knobs
-def test_resolve_mode_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_EPOCH_MODE", "unroll")
-    assert granule_step.resolve_mode("auto") == "unroll"
-    # an explicit caller choice always beats the env
-    assert granule_step.resolve_mode("xla") == "xla"
-    monkeypatch.setenv("REPRO_EPOCH_MODE", "bogus")
-    with pytest.raises(ValueError, match="REPRO_EPOCH_MODE"):
-        granule_step.resolve_mode("auto")
-
-
-def test_resolve_interpret_env_override(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    # off-TPU, "auto" must fall back to the interpreter (never dead code)
-    assert granule_step.resolve_interpret("auto") is True
-    assert granule_step.resolve_interpret(False) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert granule_step.resolve_interpret(False) is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert granule_step.resolve_interpret(True) is False
-
-
-def test_epoch_mode_env_reaches_engine(monkeypatch):
-    """REPRO_EPOCH_MODE=pallas forces the kernel body through the engine's
-    default 'auto' fuse — the CI pallas-interpret smoke stage contract —
-    and the trajectory stays bit-exact vs xla."""
-    R, C = 4, 4
-    vals = (np.arange(R * C) % 5 + 1).astype(np.float32).reshape(R, C)
-    mesh = make_mesh((1,), ("gx",))
-    monkeypatch.delenv("REPRO_EPOCH_MODE", raising=False)
-    ref = FusedEngine(_torus(R, C, vals, 4), None, mesh, K=4, fuse="xla")
-    rs = ref.run_epochs(ref.init(jax.random.key(0)), 3, donate=False)
-    monkeypatch.setenv("REPRO_EPOCH_MODE", "pallas")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    eng = FusedEngine(_torus(R, C, vals, 4), None, mesh, K=4)
-    assert eng.fuse == "auto"  # resolution happens at trace time, via env
-    st = eng.run_epochs(eng.init(jax.random.key(0)), 3, donate=False)
-    for a, b in zip(jax.tree.leaves(jax.device_get(rs)),
-                    jax.tree.leaves(jax.device_get(st))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    eng = FusedEngine(_torus(R, C, vals, 4), part, mesh, **kw)
+    assert not eng.overlap
+    assert_allreduce_matches_netlist(lambda: _torus(R, C, vals, 4), eng)
 
 
 # ------------------------------------------------------ procs batched workers

@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    ChannelGraph, FusedEngine, Network, NetworkSim,
+    ChannelGraph, FusedEngine, Network, NetworkSim, fold_mesh,
+    tiered_grid_partition,
 )
 from repro.core.compat import make_mesh
 from repro.core.distributed import GridEngine
@@ -93,7 +94,7 @@ def test_fused_lowering_registers_vs_queues():
 def test_epoch_loop_contract():
     carry = (jnp.zeros((4,)), jnp.zeros((), jnp.int32))
     out = granule_step.epoch_loop(
-        lambda c: (c[0] + 1.0, c[1] + 1), carry, 5, mode="xla"
+        lambda c: (c[0] + 1.0, c[1] + 1), carry, 5
     )
     assert int(out[1]) == 5 and float(out[0][0]) == 5.0
     # k=0 is the identity
@@ -102,26 +103,134 @@ def test_epoch_loop_contract():
     # a body that changes shapes is rejected with a clear error
     with pytest.raises(TypeError, match="preserve"):
         granule_step.epoch_loop(
-            lambda c: (jnp.zeros((5,)), c[1]), carry, 3, mode="xla"
+            lambda c: (jnp.zeros((5,)), c[1]), carry, 3
         )
 
 
-@pytest.mark.parametrize("mode", ["xla", "unroll", "pallas"])
-def test_fused_epoch_modes_bit_identical(mode):
-    """All three epoch-body strategies produce the same state trajectory."""
-    R, C = 3, 4
-    vals = np.arange(1, R * C + 1, dtype=np.float32).reshape(R, C)
-    g = ChannelGraph.torus(
-        ManycoreCell(R, C), R, C, params=make_core_params(vals), capacity=4
-    )
-    ref = FusedEngine(g, None, make_mesh((1,), ("gx",)), K=4, fuse="xla")
-    ref_st = ref.run_epochs(ref.init(jax.random.key(0)), 6)
-    eng = FusedEngine(
-        g, None, make_mesh((1,), ("gx",)), K=4, fuse=mode, pallas_interpret=True
-    )
-    st = eng.run_epochs(eng.init(jax.random.key(0)), 6)
-    for a, b in zip(jax.tree.leaves(ref_st), jax.tree.leaves(st)):
+# A toy carry (x, cycles, per-tier sums): a cycle adds 1 to x, tier t's
+# exchange adds (t + 1) * sum(x) to its sum.  The split halves hand that
+# value from issue to commit as the pending pytree.
+def _toy_cycle(c, k):
+    x, n, acc = c
+    return x + k, n + 1, acc
+
+
+def _toy_exchange(c, t, k):
+    x, n, acc = c
+    return x, n, acc.at[t].add((t + 1) * jnp.sum(x))
+
+
+def _toy_issue(c, t, k):
+    return c, (t + 1) * jnp.sum(c[0])
+
+
+def _toy_commit(c, t, pending, k):
+    x, n, acc = c
+    return x, n, acc.at[t].add(pending)
+
+
+@pytest.mark.parametrize("program", [
+    (("C", 3), ("X", 1), ("C", 3), ("X", 1), ("X", 0)),
+    (("C", 1), ("X", 0), ("C", 4), ("X", 1), ("X", 0), ("C", 1)),
+], ids=["loops", "single-cycle-blocks"])
+def test_epoch_program_split_matches_serial(program):
+    """``epoch_program`` walks a serial op list and its ``overlap_program``
+    rewrite (issue/commit halves) to the same carry, the one a plain
+    Python walk of the same ops reaches."""
+    carry = (jnp.zeros((4,), jnp.float32), jnp.zeros((), jnp.int32),
+             jnp.zeros((2,), jnp.float32))
+    consts = jnp.float32(1.0)
+    fns = dict(exchange_fn=_toy_exchange, issue_fn=_toy_issue,
+               commit_fn=_toy_commit, consts=consts)
+    split = granule_step.overlap_program(program)
+    assert any(op == "XI" for op, _ in split)
+    serial = granule_step.epoch_program(_toy_cycle, carry, program, **fns)
+    overlapped = granule_step.epoch_program(_toy_cycle, carry, split, **fns)
+
+    x, acc = 0.0, [0.0, 0.0]
+    for op, arg in program:
+        if op == "C":
+            x += arg
+        else:
+            acc[arg] += (arg + 1) * 4 * x
+    assert int(serial[1]) == sum(a for op, a in program if op == "C")
+    np.testing.assert_array_equal(np.asarray(serial[0]), np.full(4, x))
+    np.testing.assert_array_equal(np.asarray(serial[2]), np.array(acc))
+    for a, b in zip(serial, overlapped):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def assert_allreduce_matches_netlist(graph_fn, eng) -> None:
+    """Run the torus allreduce on ``eng`` to done and compare every core's
+    final state with the single netlist on ``graph_fn()``: the ring
+    handshakes fix the final state by the values alone, for any partition
+    and any sync period."""
+    sim = NetworkSim(graph_fn())
+    want = sim.run_until(
+        sim.init(jax.random.key(0)),
+        lambda s: allreduce_done(s.block_states[0]), 10_000,
+    ).block_states[0]
+    done = lambda s: allreduce_done(s.block_states[0], s.tables.active[0])  # noqa: E731
+    st = eng.run_until(eng.place(eng.init(jax.random.key(0))), done, 10_000,
+                       cache_key="done")
+    got = eng.gather_group(st, 0)
+    assert (np.asarray(want.phase) == 2).all()
+    for f in want.__dataclass_fields__:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
+            err_msg=f)
+
+
+# (rows, cols, queue capacity) of each epoch-program layout's torus; the
+# folded wafer runs wafer_64k's queue depth and sync periods on 16x16
+_LAYOUT_SIZES = {"unbatched": (3, 4, 4), "batch-rows": (8, 4, 4),
+                 "wafer-folded": (16, 16, 62)}
+
+
+def _epoch_layout(layout):
+    """A torus builder and the fused engine for one epoch-program layout."""
+    R, C, cap = _LAYOUT_SIZES[layout]
+    vals = (np.arange(R * C) % 11 + 1).astype(np.float32).reshape(R, C)
+
+    def graph():
+        return ChannelGraph.torus(
+            ManycoreCell(R, C), R, C, params=make_core_params(vals),
+            capacity=cap)
+
+    if layout == "unbatched":
+        eng = FusedEngine(graph(), None, make_mesh((1,), ("gx",)), K=4)
+    elif layout == "batch-rows":
+        eng = FusedEngine(
+            graph(), np.arange(R * C) % 2, make_mesh((1,), ("gx",)),
+            tiers=[(("gx",), 4)], batch_axes={"gx": 2})
+    else:
+        # wafer_64k's two tiers, all eight granules folded onto one device
+        mesh, batch = fold_mesh({"pod": 2, "gr": 2, "gc": 2},
+                                jax.devices()[:1])
+        eng = FusedEngine(
+            graph(), tiered_grid_partition(R, C, [(2, 1), (2, 2)]), mesh,
+            tiers=[(("pod",), 4), (("gr", "gc"), 16)], batch_axes=batch)
+    return graph, eng
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUT_SIZES))
+def test_fused_epoch_layouts_match_netlist(layout):
+    """The fused epoch body — ``epoch_loop`` per granule, the per-row walk
+    of a pure-batch engine, and the folded two-tier wafer layout — ends
+    the allreduce where the single netlist does, core for core."""
+    graph, eng = _epoch_layout(layout)
+    if layout == "wafer-folded":
+        assert eng._batched and not eng.real_axes
+    assert_allreduce_matches_netlist(graph, eng)
+
+
+@pytest.mark.parametrize("kwarg", ["fuse", "pallas_interpret"])
+def test_fused_build_rejects_retired_epoch_knobs(kwarg):
+    """The fused engine has one epoch body: the old strategy knobs are
+    unknown build kwargs, not silently ignored."""
+    with pytest.raises(TypeError, match=kwarg):
+        build_chain(3).build(engine="fused", mesh=make_mesh((1,), ("gx",)),
+                             **{kwarg: "xla"})
 
 
 # ------------------------------------------ single-granule vs single netlist

@@ -12,8 +12,8 @@ iteration and transfers hide under compute.  The contract under test:
   * the overlapped engines are **bit-identical** to the serial ones —
     full state, every epoch — on random hierarchical partitions, any
     (K_inner, K_outer), all engine paths (GraphEngine, FusedEngine,
-    signature-batched, resident pallas, and the free-running procs
-    fleet), and cycle-accurate vs the single netlist at K=(1,1).
+    signature-batched, the resident per-row body, and the free-running
+    procs fleet), and cycle-accurate vs the single netlist at K=(1,1).
 """
 import os
 import subprocess
@@ -31,6 +31,7 @@ from repro.hw.manycore import (
     ManycoreCell, allreduce_done, expected_total, make_core_params,
 )
 from repro.kernels import granule_step
+from test_fused import assert_allreduce_matches_netlist
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -225,26 +226,18 @@ def test_overlap_k11_cycle_accurate_capacity2():
         assert np.array_equal(ref, got), (t, ref, got)
 
 
-# ------------------------------------------- resident body: pallas vs xla
+# ------------------------------------------ resident body vs single netlist
 def test_overlap_resident_pallas_vs_xla_bit_identical():
-    """Under the split schedule the resident per-row body still compiles
-    to the same trajectory with fuse='pallas' (interpret, double-buffered
-    slab staging) and fuse='xla' — the kernel path stays a lowering
-    choice, not a semantics fork."""
+    """Under the split schedule the per-row resident body still ends the
+    allreduce where the single netlist does, core for core."""
     R, C = 8, 4
     vals = (np.arange(R * C) % 11 + 1).astype(np.float32).reshape(R, C)
     mesh = make_mesh((1,), ("gx",))
     part = np.arange(R * C) % 2
     kw = dict(tiers=[(("gx",), 4)], batch_axes={"gx": 2}, overlap=True)
-    ref = FusedEngine(_torus(R, C, vals, 4), part, mesh, fuse="xla", **kw)
-    pal = FusedEngine(_torus(R, C, vals, 4), part, mesh, fuse="pallas",
-                      pallas_interpret=True, **kw)
-    rs = ref.run_epochs(ref.place(ref.init(jax.random.key(0))), 4,
-                        donate=False)
-    ps = pal.run_epochs(pal.place(pal.init(jax.random.key(0))), 4,
-                        donate=False)
-    for a, b in zip(_state_leaves(rs), _state_leaves(ps)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    eng = FusedEngine(_torus(R, C, vals, 4), part, mesh, **kw)
+    assert eng.overlap
+    assert_allreduce_matches_netlist(lambda: _torus(R, C, vals, 4), eng)
 
 
 # ---------------------------------------------- free-running procs fleet

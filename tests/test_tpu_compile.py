@@ -5,19 +5,19 @@ these tests catch what interpret mode and XLA:CPU cannot: an op the chip's
 compiler refuses, a kernel layout it cannot lower, a program that does not
 fit.  Each compiles the main path's device code at its real size:
 
-  * the fused engine's epoch body for one WAFER granule (8,192 cores) in
-    the mode ``fuse="auto"`` resolves to, and that body at WAFER's and the
-    benchmark's queue sizes holds no loop but the epoch loop;
+  * the fused engine's epoch body for one WAFER granule (8,192 cores),
+    and that body at WAFER's and the benchmark's queue sizes holds no
+    loop but the epoch loop;
   * that body moves only 32-bit elements through its gathers;
-  * the same body as a resident Pallas kernel — refused by the chip's
-    compiler, which is why ``auto`` never picks it (a TPU run with
-    ``fuse="pallas"`` fails at compile time instead of falling back);
+  * the four-chip wafer's whole epoch program (``wafer256k.allreduce``:
+    real pod x gr mesh axes, gc folded as batch rows) on the 2x2 mesh;
   * the register engine's systolic tile kernel at a full-size tile.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import dataclasses
+import json
 import os
 import re
 
@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.configs.manycore import WAFER
 from repro.core import ChannelGraph, FusedEngine, fold_mesh, tiered_grid_partition
@@ -96,20 +96,16 @@ def _on(sharding, tree):
         tree)
 
 
-def _epoch_body(eng, mode, k=WAFER.k_inner):
+def _epoch_body(eng, k=WAFER.k_inner):
     return jax.jit(lambda c: granule_step.epoch_loop(
-        eng._cycle_body, c, k, consts=eng._t6_row(0),
-        mode=mode, interpret=False))
+        eng._cycle_body, c, k, consts=eng._t6_row(0)))
 
 
-def test_fused_wafer_granule_epoch_compiles(one_chip, wafer_row, monkeypatch):
-    monkeypatch.delenv("REPRO_EPOCH_MODE", raising=False)
+def test_fused_wafer_granule_epoch_compiles(one_chip, wafer_row):
     eng, row = wafer_row
-    mode = granule_step.resolve_mode("auto")
-    assert mode == "xla"
     assert int(np.prod(row[3][0].value.shape)) == WAFER.grid_rows * \
         WAFER.grid_cols // 8  # one of the 8 granules
-    compiled = _epoch_body(eng, mode).lower(_on(one_chip, row)).compile()
+    compiled = _epoch_body(eng).lower(_on(one_chip, row)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes < 1 << 30
@@ -126,7 +122,7 @@ def test_fused_wafer_epoch_has_no_queue_loop(one_chip, cfg):
     queue push is a dense select, not a scatter that XLA:TPU would lower
     to a scalar loop with one trip per queue row, every cycle."""
     eng, row = _granule_row(cfg)
-    text = _epoch_body(eng, "xla", cfg.k_inner).lower(
+    text = _epoch_body(eng, cfg.k_inner).lower(
         _on(one_chip, row)).compile().as_text()
     assert len(re.findall(r"\swhile\(", text)) == 1
     scatters = [n for n in re.findall(r'op_name="([^"]*)"', text)
@@ -141,19 +137,46 @@ def test_fused_wafer_epoch_gathers_are_32_bit(one_chip, cfg):
     XLA:TPU packs narrower elements (``pred`` four to a word) and gathers
     them one element at a time."""
     eng, row = _granule_row(cfg)
-    text = _epoch_body(eng, "xla", cfg.k_inner).lower(
+    text = _epoch_body(eng, cfg.k_inner).lower(
         _on(one_chip, row)).compile().as_text()
     kinds = re.findall(r"=\s*(\w+)\[[^\]]*\]\S*\s+gather\(", text)
     assert kinds, "no gather found in the compiled body"
     assert set(kinds) <= {"f32", "s32", "u32"}, sorted(set(kinds))
 
 
-def test_fused_wafer_granule_pallas_body_refused(one_chip, wafer_row):
-    """The resident Pallas body does not lower for a TPU (rank-0 blocks,
-    N-D row gathers): ``fuse="pallas"`` fails loudly on the chip."""
-    eng, row = wafer_row
-    with pytest.raises(Exception, match="rank >= 1|gather"):
-        _epoch_body(eng, "pallas").lower(_on(one_chip, row)).compile()
+def test_fused_wafer_four_chip_epoch_compiles(topo):
+    """The mixed real+batch epoch program of the four-chip wafer cell at
+    its real size, compiled for the 2x2 mesh: gathers move 32-bit
+    elements, and the exchange is the cell's nine ppermutes (slab, count
+    and credit for each of the pod tier's two shifts and the gr tier's
+    one)."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "chipbench", "configs",
+                           "wafer_256k.json")) as f:
+        cfg = json.load(f)
+    R, C = cfg["grid_rows"], cfg["grid_cols"]
+    lay = cfg["layout"]
+    graph = ChannelGraph.torus(
+        ManycoreCell(R, C), R, C,
+        params=make_core_params(np.ones((R, C), np.float32)),
+        capacity=cfg["queue_capacity"])
+    mesh, batch = fold_mesh(lay["axes"], topo.devices)
+    eng = FusedEngine(
+        graph, tiered_grid_partition(R, C, [tuple(t) for t in lay["tiles"]]),
+        mesh, batch_axes=batch,
+        tiers=[(tuple(ax), k) for ax, k in zip(
+            lay["tier_axes"], (cfg["k_outer"], cfg["k_inner"]))])
+    assert eng.real_axes == ("pod", "gr") and eng._batched
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, eng._spec)),
+        jax.eval_shape(eng.init, jax.random.key(0)))
+    compiled = jax.jit(eng.epoch_fn()).lower(state).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"collective-permute-start\(", text)) == 9
+    kinds = re.findall(r"=\s*(\w+)\[[^\]]*\]\S*\s+gather\(", text)
+    assert kinds and set(kinds) <= {"f32", "s32", "u32"}, sorted(set(kinds))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_systolic_tile_kernel_compiles(one_chip):
